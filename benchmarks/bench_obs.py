@@ -94,11 +94,11 @@ import jax.numpy as jnp
 from repro import obs as obs_mod, optim, perf
 from repro.core import EngineConfig, init_state, problems
 from repro.launch import distributed as dist
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from benchmarks.common import mini_bert
 
 UNROLL = 2
-mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 model = mini_bert(num_labels=4, d_model=128)
 spec = problems.make_data_optimization_spec(model.classifier_per_example, reweight=True)
 lam = problems.init_data_optimization_lam(jax.random.PRNGKey(1), reweight=True)
@@ -125,6 +125,8 @@ def _census_arm():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # a host-device census by design: never reach for a chip the parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", CENSUS_SCRIPT], capture_output=True,
                          text=True, env=env, cwd=root, timeout=900)

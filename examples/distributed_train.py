@@ -28,9 +28,9 @@ def apply_fn(theta, x):
 
 
 def main():
-    from repro.launch.mesh import AxisType, make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     print(f"devices: {len(jax.devices())}, mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     per_ex = problems.softmax_per_example(apply_fn)

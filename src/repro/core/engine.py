@@ -266,6 +266,10 @@ def make_meta_step(
     micro = cfg.scale.microbatch
 
     def meta_step(state: EngineState, base_batches, meta_batch):
+        with policy.matmul_context():
+            return _meta_step(state, base_batches, meta_batch)
+
+    def _meta_step(state: EngineState, base_batches, meta_batch):
         # obs_trace.phase = unconditional jax.named_scope (identical HLO
         # with obs on or off) + a host span iff a Tracer is activated
         with obs_trace.phase("base_unroll"):

@@ -10,28 +10,15 @@ by the caller; this kernel fuses the remaining elementwise chain — rsqrt,
 scale, product against ``g_meta``, and the per-tile partial sum of squares
 for eps = alpha/||v|| — into one pass over (vhat, g_meta).
 
-Same layout contract as ``adam_adapt``: 1-D grid over (BLK,)-tiles of the
-flattened tensor, the traced lr rides a scalar input block.
+Layout, padding and the scalar inputs (the traced lr) are
+``kernels.flat``'s, as for ``adam_adapt``.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _adafactor_kernel(sched_ref, vhat_ref, gm_ref, out_ref, ss_ref, *, eps):
-    lr = sched_ref[0]
-    vhat = vhat_ref[...].astype(jnp.float32)
-    gm = gm_ref[...].astype(jnp.float32)
-
-    diag = lr / (jnp.sqrt(vhat) + eps)
-    out = diag * gm
-    out_ref[...] = out
-    ss_ref[0] = jnp.sum(out * out)
+from repro.kernels.flat import flat_product
 
 
 def adafactor_adapt_product(
@@ -40,39 +27,17 @@ def adafactor_adapt_product(
     *,
     lr=1.0,
     eps: float = 1e-8,
-    block: int = 8 * 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Flat f32 arrays (N,). ``vhat`` must be the bias-corrected second
     moment (non-negative). Returns (v_out (N,) f32, sumsq scalar f32)."""
 
-    (n,) = vhat.shape
-    blk = min(block, n)
-    pad = (-n) % blk
-    if pad:
-        # pad vhat with ones (not zeros): 1/(sqrt(0)+eps) would be huge and,
-        # multiplied by the zero-padded g_meta, still contributes exact zeros
-        # — but ones keep the intermediate finite for any eps.
-        vhat = jnp.concatenate([vhat, jnp.ones((pad,), vhat.dtype)])
-        g_meta = jnp.concatenate([g_meta, jnp.zeros((pad,), g_meta.dtype)])
-    n_pad = n + pad
-    grid = (n_pad // blk,)
+    eps = float(eps)
 
-    sched = jnp.asarray(lr, jnp.float32).reshape(1)
-    kern = functools.partial(_adafactor_kernel, eps=float(eps))
-    out, partial_ss = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))]
-        + [pl.BlockSpec((blk,), lambda i: (i,))] * 2,
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, vhat, g_meta)
-    return out[:n], jnp.sum(partial_ss)
+    def formula(s, vhat, gm):
+        return s[0] / (jnp.sqrt(vhat) + eps) * gm
+
+    # vhat pads with ones, not zeros: 1/(sqrt(0)+eps) would be huge; with
+    # ones the padded products are exact zeros for any eps
+    return flat_product(formula, (lr,), (vhat, g_meta),
+                        pad_values=(1.0, 0.0), interpret=interpret)

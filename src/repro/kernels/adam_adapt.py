@@ -8,47 +8,18 @@ chain into one pass: each (BLK,)-tile is read once, the adaptation diagonal
 is computed in registers, and a per-tile partial sum of squares is emitted so
 the norm needs no second pass over the data.
 
-1-D grid over tiles of the flattened parameter tensor; BLK = 8 * 128 * k to
-match f32 (sublane, lane) tiling.
-
-The step index ``t`` and learning rate ``lr`` ride a (2,) scalar input
-(every grid step maps to the same block) rather than being baked in as
-static kernel params: in the hot path both are traced values
-(``state.count`` under jit, scheduled lr), and a static bake would force a
-retrace per step.
+Layout, padding and the scalar inputs are ``kernels.flat``'s. The step
+index ``t`` and learning rate ``lr`` are traced values in the hot path
+(``state.count`` under jit, scheduled lr): the bias corrections are computed
+from them outside the kernel and ride its SMEM scalar vector, so no step
+forces a retrace.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _adapt_kernel(sched_ref, g_ref, m_ref, v_ref, gm_ref, out_ref, ss_ref, *, b1, b2, eps):
-    t = sched_ref[0]
-    lr = sched_ref[1]
-    g = g_ref[...].astype(jnp.float32)
-    m = m_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    gm = gm_ref[...].astype(jnp.float32)
-
-    bc1 = 1.0 - b1**t
-    bc2 = 1.0 - b2**t
-    m1 = b1 * m + (1.0 - b1) * g
-    v1 = b2 * v + (1.0 - b2) * g * g
-    mhat = m1 / bc1
-    vhat = v1 / bc2
-    sq = jnp.sqrt(vhat)
-    denom = sq + eps
-    a = (1.0 - b1) / bc1
-    b = (1.0 - b2) / bc2
-    diag = lr * (a / denom - mhat * b * g / (jnp.maximum(sq, 1e-15) * denom * denom))
-    out = diag * gm
-    out_ref[...] = out
-    ss_ref[0] = jnp.sum(out * out)
+from repro.kernels.flat import flat_product
 
 
 def adam_adapt_product(
@@ -62,38 +33,28 @@ def adam_adapt_product(
     b2: float = 0.999,
     eps: float = 1e-8,
     lr=1.0,
-    block: int = 8 * 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Flat f32 arrays (N,). Returns (v_out (N,) f32, sumsq scalar f32).
 
-    ``t`` and ``lr`` may be python numbers or traced scalars (they are fed
-    to the kernel as a (2,) input array, not static params)."""
+    ``t`` and ``lr`` may be python numbers or traced scalars."""
 
-    (n,) = g.shape
-    blk = min(block, n)
-    pad = (-n) % blk
-    if pad:
-        zeros = jnp.zeros((pad,), g.dtype)
-        g, m, v, g_meta = (jnp.concatenate([x, zeros]) for x in (g, m, v, g_meta))
-    n_pad = n + pad
-    grid = (n_pad // blk,)
+    b1, b2, eps = float(b1), float(b2), float(eps)
+    t = jnp.asarray(t, jnp.float32)
+    bc1 = 1.0 - b1**t
+    bc2 = 1.0 - b2**t
 
-    sched = jnp.stack([jnp.asarray(t, jnp.float32), jnp.asarray(lr, jnp.float32)])
-    kern = functools.partial(_adapt_kernel, b1=float(b1), b2=float(b2), eps=float(eps))
-    out, partial_ss = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((2,), lambda i: (0,))]
-        + [pl.BlockSpec((blk,), lambda i: (i,))] * 4,
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, g, m, v, g_meta)
-    return out[:n], jnp.sum(partial_ss)
+    def formula(s, g, m, v, gm):
+        lr, bc1, bc2, a, b = s[0], s[1], s[2], s[3], s[4]
+        m1 = b1 * m + (1.0 - b1) * g
+        v1 = b2 * v + (1.0 - b2) * g * g
+        mhat = m1 / bc1
+        vhat = v1 / bc2
+        sq = jnp.sqrt(vhat)
+        denom = sq + eps
+        diag = lr * (a / denom - mhat * b * g / (jnp.maximum(sq, 1e-15) * denom * denom))
+        return diag * gm
+
+    scalars = (lr, bc1, bc2, (1.0 - b1) / bc1, (1.0 - b2) / bc2)
+    return flat_product(formula, scalars, (g, m, v, g_meta),
+                        pad_values=(0.0, 0.0, 0.0, 0.0), interpret=interpret)

@@ -28,6 +28,9 @@ predicate is consulted on the concrete call (static shapes/dtypes only — it
 runs at trace time). An ineligible or unregistered choice falls through to
 the next entry in the order, ending at ``ref`` which must always be
 registered and always eligible; the fallback is recorded, never an error.
+Forcing ``pallas-tpu`` where the JAX backend is not a TPU raises: the
+compiled kernel cannot run there, and quietly running another backend
+would hide that the device is missing.
 Ragged/non-tile-aligned shapes are therefore safe on every backend: the
 flat adaptation kernels pad internally (pad-or-fallback), and shapes the
 blockwise-CE kernel cannot tile fall back to ``ref``.
@@ -200,10 +203,13 @@ def get_kernel(name: str, *, backend: Optional[str] = None) -> Callable[..., Any
         tried = []
         for cand in order:
             if cand == "pallas-tpu" and jax.default_backend() != "tpu":
-                # compiled Pallas only exists on a TPU runtime; even a forced
-                # choice degrades safely rather than crashing in lowering
-                tried.append(f"{cand}:unavailable")
-                continue
+                # only a forced choice reaches here (the platform default
+                # never orders pallas-tpu off a TPU): refuse it rather than
+                # run something other than what was asked for
+                raise RuntimeError(
+                    f"kernel {name!r}: backend 'pallas-tpu' was forced "
+                    f"({ENV_VAR} or backend=) but the JAX backend is "
+                    f"{jax.default_backend()!r}, not 'tpu'")
             impl = per_kernel.get(cand)
             if impl is None:
                 tried.append(f"{cand}:unregistered")
@@ -238,10 +244,14 @@ def _flat_inputs_ok(*arrays, **kwargs) -> bool:
 def _ce_tiles_ok(logits, targets, **kwargs) -> bool:
     """The compiled blockwise-CE kernel needs a lane-aligned vocabulary
     (V % 128) — `_pick_blocks` would otherwise fall back to BV=V, which
-    defeats the VMEM streaming the kernel exists for. Interpret mode has
-    no such constraint (any block shape interprets)."""
+    defeats the VMEM streaming the kernel exists for — and rows that
+    tile into f32 sublanes (R % 8), or few enough (R <= 128) to form one
+    row block. Interpret mode has no such constraint (any block shape
+    interprets)."""
 
-    return logits.ndim == 2 and logits.shape[-1] % 128 == 0
+    rows = logits.shape[0]
+    return (logits.ndim == 2 and logits.shape[-1] % 128 == 0
+            and (rows % 8 == 0 or rows <= 128))
 
 
 _ATTN_DTYPES = ("float32", "bfloat16", "float16")

@@ -12,9 +12,9 @@ Two kernels, both registered through :mod:`repro.kernels.dispatch`:
     kernels, so activation memory is O(B*S*H*Dh) instead of O(S*T).
     Supports causal masking, logit softcap (tanh), and sliding-window
     masking gated by a *traced* per-layer ``local_flag`` (the flag rides
-    into the kernel as a tiny int32 input with a constant index map —
-    the adam_adapt idiom — so heterogeneous local/global layers inside a
-    ``lax.scan`` over layers work without retracing).
+    into the kernel as a tiny int32 SMEM input, so heterogeneous
+    local/global layers inside a ``lax.scan`` over layers work without
+    retracing).
 
 GQA without grid races: q is laid out as ``(B*KV, G, S, Dh)`` so each
 grid cell owns one (batch, kv-head) pair and its whole query group. The
@@ -39,6 +39,11 @@ Both kernels carry a ``ref`` twin that reproduces the existing
 selection between ``_sdpa`` and ``_chunked_sdpa``), so the default CPU
 dispatch is bitwise-identical to the pre-kernel code and every tier-1
 pin (scan-prefill bitwise equality, attribution FLOP bands) holds.
+
+TPU layout: per-row values (query positions, ``m``, ``l``, ``lse``, the
+backward's ``delta``) are (rows, 1) columns and key positions a (1, bk)
+row, so a score tile's mask is one broadcast compare and every block's
+last two dimensions tile (8, 128) or span the whole array dimension.
 
 Masking convention shared with the ref path: padded positions are
 ``-1`` sentinels, masked scores are set to the finite ``NEG = -1e30``
@@ -99,15 +104,19 @@ def _flag_array(local_flag, window: int):
 
 
 def _tile_mask(qp, kp, *, causal: bool, window: int, use_window: bool, lf):
-    """(bq, bk) validity for one score tile. ``qp``/``kp`` are int32
-    position rows; -1 marks padding. ``lf`` is the traced 0/1 gate."""
-    valid = (kp[None, :] >= 0) & (qp[:, None] >= 0)
+    """Validity of one score tile: ``qp`` is a (rows, 1) column and ``kp``
+    a (1, bk) row of int32 positions; -1 marks padding. ``lf`` is the
+    traced 0/1 gate."""
+    valid = (kp >= 0) & (qp >= 0)
     if causal:
-        valid &= kp[None, :] <= qp[:, None]
+        valid &= kp <= qp
     if use_window:
-        local = (qp[:, None] - kp[None, :]) < window
+        local = (qp - kp) < window
         valid &= jnp.where(lf != 0, local, True)
     return valid
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -126,46 +135,43 @@ def _fwd_kernel(lf_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bq = q_ref.shape[2]
+    bq, dh = q_ref.shape[2], q_ref.shape[3]
     rows = g * bq
-    q = q_ref[0].astype(jnp.float32).reshape(rows, q_ref.shape[3])
+    q = q_ref[0].astype(jnp.float32).reshape(rows, dh)
     k = k_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    valid = _tile_mask(qp_ref[0], kp_ref[0], causal=causal, window=window,
-                       use_window=window > 0, lf=lf_ref[0])
-    valid = jnp.broadcast_to(valid[None], (g, bq, k.shape[0])).reshape(
-        rows, k.shape[0])
+    valid = _tile_mask(qp_ref[0].reshape(rows, 1), kp_ref[...], causal=causal,
+                       window=window, use_window=window > 0, lf=lf_ref[0])
     s = jnp.where(valid, s, NEG)
 
-    m_prev = m_ref[...].reshape(rows)
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.where(valid, jnp.exp(s - m_cur[:, None]), 0.0)
+    m_prev = m_ref[...]                          # (rows, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)
     alpha = jnp.where(m_prev <= NEG, 0.0,
                       jnp.exp(jnp.minimum(m_prev - m_cur, 0.0)))
-    l_ref[...] = (l_ref[...].reshape(rows) * alpha
-                  + jnp.sum(p, axis=1)).reshape(g, bq)
-    m_ref[...] = m_cur.reshape(g, bq)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_cur
     pv = jax.lax.dot_general(p, v_ref[0].astype(jnp.float32),
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    acc_ref[...] = (acc_ref[...].reshape(rows, -1) * alpha[:, None]
-                    + pv).reshape(acc_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(j == nj - 1)
     def _finalize():
-        l = l_ref[...].reshape(rows)
-        m = m_ref[...].reshape(rows)
-        out = acc_ref[...].reshape(rows, -1) / jnp.maximum(l, _TINY)[:, None]
-        o_ref[0] = out.reshape(o_ref.shape[1:])
-        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, _TINY)), NEG)
-        lse_ref[0] = lse.reshape(g, bq)
+        l = l_ref[...]
+        out = acc_ref[...] / jnp.maximum(l, _TINY)
+        o_ref[0] = out.reshape(g, bq, dh)
+        lse = jnp.where(l > 0, m_ref[...] + jnp.log(jnp.maximum(l, _TINY)), NEG)
+        lse_ref[0] = lse.reshape(g, bq, 1)
 
 
 def _layouts(q, k, v, q_pos, kv_pos, bq, bk):
-    """Fold GQA into per-(batch, kv-head) blocks and pad to tiles."""
+    """Fold GQA into per-(batch, kv-head) blocks and pad to tiles. Query
+    positions are repeated per group member, (B, G, Sp, 1), so a q block's
+    (G*bq) rows carry their own positions."""
     b, s, h, dh = q.shape
     t, kv = k.shape[1], k.shape[2]
     g = h // kv
@@ -174,6 +180,7 @@ def _layouts(q, k, v, q_pos, kv_pos, bq, bk):
     k3 = _pad_to(k.transpose(0, 2, 1, 3).reshape(b * kv, t, dh), 1, bk, 0)
     v3 = _pad_to(v.transpose(0, 2, 1, 3).reshape(b * kv, t, dh), 1, bk, 0)
     qp = _pad_to(q_pos.astype(jnp.int32), 1, bq, -1)
+    qp = jnp.broadcast_to(qp[:, None, :, None], (b, g, qp.shape[1], 1))
     kp = _pad_to(kv_pos.astype(jnp.int32).reshape(1, t), 1, bk, -1)
     return q4, k3, v3, qp, kp, (b, s, h, dh, t, kv, g)
 
@@ -191,8 +198,8 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, lf, softcap, window, causal,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bb, i, j: (0,)),
-            pl.BlockSpec((1, bq), lambda bb, i, j, kvh=kv: (bb // kvh, i)),
+            _SMEM,
+            pl.BlockSpec((1, g, bq, 1), lambda bb, i, j, kvh=kv: (bb // kvh, 0, i, 0)),
             pl.BlockSpec((1, bk), lambda bb, i, j: (0, j)),
             pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, i, 0)),
             pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, j, 0)),
@@ -200,17 +207,19 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, lf, softcap, window, causal,
         ],
         out_specs=[
             pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, i, 0)),
-            pl.BlockSpec((1, g, bq), lambda bb, i, j: (bb, 0, i)),
+            pl.BlockSpec((1, g, bq, 1), lambda bb, i, j: (bb, 0, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, g, sp, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bh, g, sp), jnp.float32),
+            jax.ShapeDtypeStruct((bh, g, sp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq, dh), jnp.float32),
+            pltpu.VMEM((g * bq, 1), jnp.float32),
+            pltpu.VMEM((g * bq, 1), jnp.float32),
+            pltpu.VMEM((g * bq, dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lf, qp, kp, q4, k3, v3)
     # (B*KV, G, Sp, Dh) -> (B, S, H, Dh)
@@ -224,10 +233,9 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, lf, softcap, window, causal,
 
 
 def _bwd_tile(q, k, v, do, qp, kp, lf, lse, delta,
-              *, softcap, window, causal, scale, g, bq):
-    """Recompute p/ds for one tile. q/do are (g*bq, Dh) row blocks,
-    k/v are (bk, Dh); lse/delta are (g*bq,) rows."""
-    rows, bk = q.shape[0], k.shape[0]
+              *, softcap, window, causal, scale):
+    """Recompute p/ds for one tile. q/do are (rows, Dh) row blocks, k/v
+    (bk, Dh); qp/lse/delta are (rows, 1) columns, kp a (1, bk) row."""
     s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
     if softcap:
@@ -239,15 +247,24 @@ def _bwd_tile(q, k, v, do, qp, kp, lf, lse, delta,
         dcap = 1.0
     valid = _tile_mask(qp, kp, causal=causal, window=window,
                        use_window=window > 0, lf=lf)
-    valid = jnp.broadcast_to(valid[None], (g, bq, bk)).reshape(rows, bk)
     # lse == NEG marks fully-masked/padded rows; exp would overflow to
     # +inf in the dead branch, so clamp the subtrahend first.
     lse_safe = jnp.where(lse <= NEG, 0.0, lse)
-    p = jnp.where(valid, jnp.exp(s - lse_safe[:, None]), 0.0)
+    p = jnp.where(valid, jnp.exp(s - lse_safe), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * dcap * scale
+    ds = p * (dp - delta) * dcap * scale
     return p, ds
+
+
+def _tile_inputs(g, qp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref):
+    bq, dh = q_ref.shape[2], q_ref.shape[3]
+    rows = g * bq
+    return (q_ref[0].astype(jnp.float32).reshape(rows, dh),
+            k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
+            do_ref[0].astype(jnp.float32).reshape(rows, dh),
+            qp_ref[0].reshape(rows, 1), lse_ref[0].reshape(rows, 1),
+            delta_ref[0].reshape(rows, 1))
 
 
 def _dq_kernel(lf_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref,
@@ -259,24 +276,16 @@ def _dq_kernel(lf_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    bq, dh = q_ref.shape[2], q_ref.shape[3]
-    rows = g * bq
-    q = q_ref[0].astype(jnp.float32).reshape(rows, dh)
-    do = do_ref[0].astype(jnp.float32).reshape(rows, dh)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    _, ds = _bwd_tile(q, k, v, do, qp_ref[0], kp_ref[0], lf_ref[0],
-                      lse_ref[0].reshape(rows), delta_ref[0].reshape(rows),
-                      softcap=softcap, window=window, causal=causal,
-                      scale=scale, g=g, bq=bq)
-    dq_acc[...] = (dq_acc[...].reshape(rows, dh)
-                   + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-                   ).reshape(dq_acc.shape)
+    q, k, v, do, qp, lse, delta = _tile_inputs(
+        g, qp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
+    _, ds = _bwd_tile(q, k, v, do, qp, kp_ref[...], lf_ref[0], lse, delta,
+                      softcap=softcap, window=window, causal=causal, scale=scale)
+    dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[...]
+        dq_ref[0] = dq_acc[...].reshape(dq_ref.shape[1:])
 
 
 def _dkv_kernel(lf_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref,
@@ -289,16 +298,10 @@ def _dkv_kernel(lf_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    bq, dh = q_ref.shape[2], q_ref.shape[3]
-    rows = g * bq
-    q = q_ref[0].astype(jnp.float32).reshape(rows, dh)
-    do = do_ref[0].astype(jnp.float32).reshape(rows, dh)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    p, ds = _bwd_tile(q, k, v, do, qp_ref[0], kp_ref[0], lf_ref[0],
-                      lse_ref[0].reshape(rows), delta_ref[0].reshape(rows),
-                      softcap=softcap, window=window, causal=causal,
-                      scale=scale, g=g, bq=bq)
+    q, k, v, do, qp, lse, delta = _tile_inputs(
+        g, qp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
+    p, ds = _bwd_tile(q, k, v, do, qp, kp_ref[...], lf_ref[0], lse, delta,
+                      softcap=softcap, window=window, causal=causal, scale=scale)
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
@@ -319,62 +322,62 @@ def _bwd_impl(q, k, v, q_pos, kv_pos, lf, out, lse, g_out,
     # delta = rowsum(dO * O), computed once in plain jnp (f32)
     delta = jnp.sum(g_out.astype(jnp.float32) * out.astype(jnp.float32), -1)
     delta = delta.reshape(b, s, kv, g).transpose(0, 2, 3, 1)
-    delta = _pad_to(delta.reshape(b * kv, g, s), 2, bq, 0)
-    # lse from the forward is already padded (B*KV, G, Sp)
+    delta = _pad_to(delta.reshape(b * kv, g, s), 2, bq, 0)[..., None]
+    # lse from the forward is already padded (B*KV, G, Sp, 1)
     bh, sp, tp = q4.shape[0], q4.shape[2], k3.shape[1]
     nq, nk = sp // bq, tp // bk
     scale = 1.0 / math.sqrt(dh)
     common = dict(softcap=float(softcap), window=int(window),
                   causal=bool(causal), scale=scale, g=g)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
-    row_specs = [
-        pl.BlockSpec((1,), lambda bb, i, j: (0,)),                       # lf
-        pl.BlockSpec((1, bq), lambda bb, i, j, kvh=kv: (bb // kvh, i)),  # qp
-        pl.BlockSpec((1, bk), lambda bb, i, j: (0, j)),                  # kp
-        pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, i, 0)),    # q
-        pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, j, 0)),          # k
-        pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, j, 0)),          # v
-        pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, i, 0)),    # do
-        pl.BlockSpec((1, g, bq), lambda bb, i, j: (bb, 0, i)),           # lse
-        pl.BlockSpec((1, g, bq), lambda bb, i, j: (bb, 0, i)),           # delta
-    ]
+    def specs(qi, kj):
+        """BlockSpecs of the nine inputs, given how the (bb, i, j) grid
+        index picks the query tile (``qi``) and the KV tile (``kj``)."""
+        return [
+            _SMEM,                                                          # lf
+            pl.BlockSpec((1, g, bq, 1),
+                         lambda bb, i, j, kvh=kv: (bb // kvh, 0, qi(i, j), 0)),  # qp
+            pl.BlockSpec((1, bk), lambda bb, i, j: (0, kj(i, j))),          # kp
+            pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, qi(i, j), 0)),  # q
+            pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, kj(i, j), 0)),  # k
+            pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, kj(i, j), 0)),  # v
+            pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, qi(i, j), 0)),  # do
+            pl.BlockSpec((1, g, bq, 1), lambda bb, i, j: (bb, 0, qi(i, j), 0)),   # lse
+            pl.BlockSpec((1, g, bq, 1), lambda bb, i, j: (bb, 0, qi(i, j), 0)),   # delta
+        ]
+
+    args = (lf, qp, kp, q4, k3, v3, do4, lse, delta)
+    # dq: query tiles on the middle axis, KV tiles innermost
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid=(bh, nq, nk),
-        in_specs=row_specs,
+        in_specs=specs(lambda i, j: i, lambda i, j: j),
         out_specs=[pl.BlockSpec((1, g, bq, dh),
                                 lambda bb, i, j: (bb, 0, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, g, sp, dh), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((g, bq, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g * bq, dh), jnp.float32)],
+        compiler_params=semantics,
         interpret=interpret,
-    )(lf, qp, kp, q4, k3, v3, do4, lse, delta)[0]
+    )(*args)[0]
 
-    # dk/dv: grid iterates KV tiles on the middle axis, q tiles innermost,
-    # so the (bk, Dh) scratch accumulates over every query block of one KV
-    # tile before finalizing.
-    col_specs = [
-        pl.BlockSpec((1,), lambda bb, i, j: (0,)),
-        pl.BlockSpec((1, bq), lambda bb, i, j, kvh=kv: (bb // kvh, j)),
-        pl.BlockSpec((1, bk), lambda bb, i, j: (0, i)),
-        pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, j, 0)),
-        pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, i, 0)),
-        pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, i, 0)),
-        pl.BlockSpec((1, g, bq, dh), lambda bb, i, j: (bb, 0, j, 0)),
-        pl.BlockSpec((1, g, bq), lambda bb, i, j: (bb, 0, j)),
-        pl.BlockSpec((1, g, bq), lambda bb, i, j: (bb, 0, j)),
-    ]
+    # dk/dv: KV tiles on the middle axis, query tiles innermost, so the
+    # (bk, Dh) scratch accumulates over every query block of one KV tile
+    # before finalizing.
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
         grid=(bh, nk, nq),
-        in_specs=col_specs,
+        in_specs=specs(lambda i, j: j, lambda i, j: i),
         out_specs=[pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, i, 0)),
                    pl.BlockSpec((1, bk, dh), lambda bb, i, j: (bb, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, tp, dh), jnp.float32),
                    jax.ShapeDtypeStruct((bh, tp, dh), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
+        compiler_params=semantics,
         interpret=interpret,
-    )(lf, qp, kp, q4, k3, v3, do4, lse, delta)
+    )(*args)
 
     dq = dq[:, :, :s].reshape(b, kv, g, s, dh).transpose(0, 3, 1, 2, 4)
     dq = dq.reshape(b, s, h, dh).astype(q.dtype)
@@ -451,9 +454,12 @@ def flash_attention_ref(q, k, v, q_pos, kv_pos, local_flag=None, *,
             q.reshape(b, s, kv, h // kv, dh), k, v, q_pos, kv_pos,
             chunk=chunk, softcap=softcap, local_flag=local_flag,
             window=window, causal=causal)
-    mask = (attn.make_mask(q_pos, kv_pos, causal=True,
+    # a sliding window applies without causality too (as in _chunked_sdpa
+    # and the kernel's _tile_mask); only the all-keys case needs no mask
+    windowed = bool(window) and local_flag is not None
+    mask = (attn.make_mask(q_pos, kv_pos, causal=causal,
                            local_flag=local_flag, window=window)
-            if causal else None)
+            if causal or windowed else None)
     return attn._sdpa(q, k, v, mask, softcap=softcap)
 
 
@@ -488,7 +494,7 @@ def merge_partials(o, lse):
 
 
 def _decode_kernel(pos_ref, lf_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                   *, softcap, window, scale, t, split):
+                   *, softcap, window, scale, t, split, kv):
     si = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)           # (G, Dh)
     k = k_ref[0].astype(jnp.float32)           # (split, Dh)
@@ -498,19 +504,19 @@ def _decode_kernel(pos_ref, lf_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                             preferred_element_type=jnp.float32) * scale
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0) // kv]
     idx = si * split + jax.lax.broadcasted_iota(jnp.int32, (g, split), 1)
     valid = (idx <= pos) & (idx < t)
     if window > 0:
         local = (pos - idx) < window
         valid &= jnp.where(lf_ref[0] != 0, local, True)
     s = jnp.where(valid, s, NEG)
-    m = jnp.max(s, axis=1)
-    p = jnp.where(valid, jnp.exp(s - m[:, None]), 0.0)
-    l = jnp.sum(p, axis=1)
+    m = jnp.max(s, axis=1, keepdims=True)      # (G, 1)
+    p = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=1, keepdims=True)
     o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    o_ref[0, 0] = o / jnp.maximum(l, _TINY)[:, None]
+    o_ref[0, 0] = o / jnp.maximum(l, _TINY)
     lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, _TINY)), NEG)
 
 
@@ -525,38 +531,40 @@ def flash_decode(q, k, v, q_pos, local_flag=None, *, softcap=0.0, window=0,
     bh = b * kv
     if n_splits is None:
         n_splits = pick_splits(t, bh)
-    split = math.ceil(t / n_splits)
+    # KV spans tile f32 sublanes; a span past T is masked (idx < t) and
+    # merges as an empty split
+    split = -(-math.ceil(t / n_splits) // 8) * 8
     tp = split * n_splits
     q3 = q[:, 0].reshape(b, kv, g, dh).reshape(bh, g, dh)
     k3 = _pad_to(k.transpose(0, 2, 1, 3).reshape(bh, t, dh), 1, split, 0)
     v3 = _pad_to(v.transpose(0, 2, 1, 3).reshape(bh, t, dh), 1, split, 0)
-    pos = q_pos.astype(jnp.int32).reshape(b, 1)
+    pos = q_pos.astype(jnp.int32).reshape(b)
     use_window = window if (window and local_flag is not None) else 0
     lf = _flag_array(local_flag, use_window)
     kernel = functools.partial(
         _decode_kernel, softcap=float(softcap or 0.0), window=int(use_window),
-        scale=1.0 / math.sqrt(dh), t=t, split=split)
+        scale=1.0 / math.sqrt(dh), t=t, split=split, kv=kv)
     o_part, lse_part = pl.pallas_call(
         kernel,
         grid=(bh, n_splits),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, si, kvh=kv: (bb // kvh, 0)),
-            pl.BlockSpec((1,), lambda bb, si: (0,)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((1, g, dh), lambda bb, si: (bb, 0, 0)),
             pl.BlockSpec((1, split, dh), lambda bb, si: (bb, si, 0)),
             pl.BlockSpec((1, split, dh), lambda bb, si: (bb, si, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, dh), lambda bb, si: (bb, si, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda bb, si: (bb, si, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda bb, si: (bb, si, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, n_splits, g, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bh, n_splits, g), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n_splits, g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(pos, lf, q3, k3, v3)
-    out = merge_partials(o_part, lse_part)     # (B*KV, G, Dh)
+    out = merge_partials(o_part, lse_part[..., 0])     # (B*KV, G, Dh)
     out = out.reshape(b, kv, g, dh).reshape(b, 1, h, dh)
     return out.astype(q.dtype)
 

@@ -12,31 +12,15 @@ is the diagonal this kernel fuses against ``g_meta`` — one pass over
 (g, m, g_meta) emitting the product tile plus a per-tile partial sum of
 squares for the eps = alpha/||v|| step size (no second norm pass).
 
-Same layout contract as ``adam_adapt``: 1-D grid over (BLK,)-tiles of the
-flattened tensor, traced scalars (lr) ride a scalar input block.
+Layout, padding and the scalar inputs (the traced lr) are
+``kernels.flat``'s, as for ``adam_adapt``.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _lion_kernel(sched_ref, g_ref, m_ref, gm_ref, out_ref, ss_ref, *, b1, delta):
-    lr = sched_ref[0]
-    g = g_ref[...].astype(jnp.float32)
-    m = m_ref[...].astype(jnp.float32)
-    gm = gm_ref[...].astype(jnp.float32)
-
-    c = b1 * m + (1.0 - b1) * g
-    ad = jnp.abs(c) + delta
-    diag = lr * (1.0 - b1) * delta / (ad * ad)
-    out = diag * gm
-    out_ref[...] = out
-    ss_ref[0] = jnp.sum(out * out)
+from repro.kernels.flat import flat_product
 
 
 def lion_adapt_product(
@@ -47,35 +31,17 @@ def lion_adapt_product(
     lr=1.0,
     b1: float = 0.9,
     delta: float = 1e-3,
-    block: int = 8 * 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Flat f32 arrays (N,). Returns (v_out (N,) f32, sumsq scalar f32)."""
 
-    (n,) = g.shape
-    blk = min(block, n)
-    pad = (-n) % blk
-    if pad:
-        zeros = jnp.zeros((pad,), g.dtype)
-        g, m, g_meta = (jnp.concatenate([x, zeros]) for x in (g, m, g_meta))
-    n_pad = n + pad
-    grid = (n_pad // blk,)
+    b1, delta = float(b1), float(delta)
 
-    sched = jnp.asarray(lr, jnp.float32).reshape(1)
-    kern = functools.partial(_lion_kernel, b1=float(b1), delta=float(delta))
-    out, partial_ss = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))]
-        + [pl.BlockSpec((blk,), lambda i: (i,))] * 3,
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
-        ],
-        interpret=interpret,
-    )(sched, g, m, g_meta)
-    return out[:n], jnp.sum(partial_ss)
+    def formula(s, g, m, gm):
+        c = b1 * m + (1.0 - b1) * g
+        ad = jnp.abs(c) + delta
+        diag = s[0] * (1.0 - b1) * delta / (ad * ad)
+        return diag * gm
+
+    return flat_product(formula, (lr,), (g, m, g_meta),
+                        pad_values=(0.0, 0.0, 0.0), interpret=interpret)

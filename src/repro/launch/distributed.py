@@ -36,7 +36,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-import jax.flatten_util
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -51,7 +50,7 @@ from repro.core.engine import (
     make_meta_step,
     step_metrics,
 )
-from repro.launch.mesh import data_axes, shard_map
+from repro.launch.mesh import data_axes
 from repro.obs import trace as obs_trace
 from repro.optim import Optimizer
 from repro.scale import accum as accum_mod
@@ -79,8 +78,18 @@ def flat_pmean(tree: PyTree, axes) -> PyTree:
     model-sharded leaves into full-size reduce buffers. Callers pick this
     bucket for pure-DDP meshes and ``tree_pmean`` otherwise."""
 
-    flat, unravel = jax.flatten_util.ravel_pytree(tree)
-    return unravel(jax.lax.pmean(flat, axes))
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    flat = jax.lax.pmean(jnp.concatenate([x.reshape(-1) for x in leaves]), axes)
+    offsets = [0]
+    for x in leaves:
+        offsets.append(offsets[-1] + x.size)
+    pieces = [jax.lax.slice_in_dim(flat, a, b) for a, b in zip(offsets, offsets[1:])]
+    # the barrier keeps the TPU compiler from rewriting slice-then-reshape
+    # of a (768, 4) leaf as a reshape of the whole bucket to (N/4, 4),
+    # whose tiled layout pads 4 lanes to 128: 32x the bucket in HBM
+    pieces = jax.lax.optimization_barrier(pieces)
+    out = [p.reshape(x.shape).astype(x.dtype) for p, x in zip(pieces, leaves)]
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def tree_pmean(tree: PyTree, axes) -> PyTree:
@@ -156,6 +165,10 @@ def make_manual_step(
         if a not in dp:
             auto_extent *= mesh.shape[a]
     bucket_pmean = flat_pmean if auto_extent == 1 else tree_pmean
+    # with no live auto axis the region is manual over the whole mesh: a
+    # Pallas (Mosaic) kernel cannot sit under an auto axis, even of size 1,
+    # because the partitioner cannot split a custom call
+    manual = set(mesh.axis_names) if auto_extent == 1 else set(dp)
     method = cfg.resolve()
     policy = cfg.scale.resolve()
     spec = policy_mod.apply_to_spec(spec, policy)
@@ -184,6 +197,10 @@ def make_manual_step(
         return jax.tree_util.tree_map(lambda r, gl: r.astype(gl.dtype), g_red, g_loc)
 
     def local_step(state: EngineState, base_batches, meta_batch):
+        with policy.matmul_context():
+            return _local_step(state, base_batches, meta_batch)
+
+    def _local_step(state: EngineState, base_batches, meta_batch):
         lam = state.lam
 
         # ---- base unroll: standard DDP (one pmean per base step), shared
@@ -262,9 +279,9 @@ def make_manual_step(
             jax.tree_util.tree_map(lambda _: P(), state),
             {k: P() for k in metric_keys},
         )
-        fn = shard_map(
-            local_step, mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(dp), check=False,
+        fn = jax.shard_map(
+            local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            axis_names=manual, check_vma=False,
         )
         return fn(state, base_batches, meta_batch)
 

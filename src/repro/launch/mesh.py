@@ -1,4 +1,4 @@
-"""Production mesh construction + JAX version-compat shims.
+"""Mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; tests see 1 device).
@@ -7,74 +7,20 @@ Topology: TPU v5e, 256 chips/pod (16x16 ICI). Single-pod mesh (data=16,
 model=16); multi-pod adds a leading pod axis over DCI: (pod=2, data=16,
 model=16) = 512 chips. The batch shards over ("pod", "data"); tensor/expert
 parallelism over "model".
-
-Compat: the codebase targets the modern sharding surface
-(``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.shard_map(..., axis_names=..., check_vma=...)``) but must also run on
-jax 0.4.x where AxisType does not exist and shard_map lives in
-``jax.experimental`` with the (check_rep, auto) spelling. Everything in this
-repo goes through the shims below instead of touching those APIs directly.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5: explicit/auto/manual axis types exist
-    from jax.sharding import AxisType
-
-    _HAS_AXIS_TYPE = True
-except ImportError:  # jax 0.4.x: meshes are implicitly all-auto
-    class AxisType:  # minimal stand-in so call sites keep one spelling
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPE = False
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates jax versions without ``axis_types``
-    (0.4.x meshes are all-auto, which is exactly what the stand-in means)."""
-
-    if _HAS_AXIS_TYPE and axis_types is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=tuple(axis_types), devices=devices)
-    if axis_types is not None and any(t != AxisType.Auto for t in axis_types):
-        raise NotImplementedError(
-            "this jax version predates sharding AxisType; only all-Auto meshes "
-            f"are available here (requested {tuple(axis_types)})"
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), devices=devices)
-
-
-def shard_map(f, mesh, *, in_specs, out_specs, axis_names=None, check: bool = False):
-    """Version-portable partial-manual shard_map.
-
-    ``axis_names``: the axes made MANUAL (the modern ``jax.shard_map``
-    spelling); remaining mesh axes stay auto for the partitioner. On jax
-    0.4.x this is translated to the experimental API's complement
-    ``auto=`` set, and ``check`` maps check_vma -> check_rep.
-    """
-
-    manual = frozenset(axis_names) if axis_names is not None else frozenset(mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             axis_names=set(manual), check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:
@@ -83,5 +29,9 @@ def data_axes(mesh) -> Tuple[str, ...]:
 
 
 def make_host_mesh():
-    """1-device mesh for CPU tests/benches (same axis names, sizes 1)."""
-    return make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto, AxisType.Auto))
+    """Data-parallel mesh over every device this process sees:
+    (data=len(jax.devices()), model=1). One chip (or the single CPU device
+    of the tests) gives (1, 1); a four-chip host gives (4, 1)."""
+    n = len(jax.devices())
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

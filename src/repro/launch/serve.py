@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro import configs, perf, serve
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve import greedy_generate  # noqa: F401  (back-compat re-export)
 
@@ -91,6 +92,7 @@ def main():
                          "burn-rate alert (which also triggers a postmortem "
                          "dump)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     if cfg.family == "encoder":

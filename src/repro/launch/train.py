@@ -7,20 +7,28 @@
 Wires together: config registry -> synthetic noisy LM data -> Model ->
 data-optimization BilevelSpec -> ``repro.api.MetaLearner`` (which owns the
 Engine or the single-sync shard_map schedule + checkpointing). On the CPU
-container use --smoke; on a TPU cluster the same script runs the full
-config on the production mesh. ``--method`` accepts any registered
-hypergradient method, including third-party registrations.
+use --smoke; on a TPU the same script runs the full config (e.g.
+``--arch bert-base --batch 32 --seq 128``). The mesh is data-parallel over
+every device present, ``(data=len(jax.devices()), model=1)``.
+``--method`` accepts any registered hypergradient method, including
+third-party registrations.
 
-repro.scale knobs: ``--precision`` picks the policy (f32/bf16/f16),
-``--microbatch`` forces an accumulation factor, and ``--hbm-budget-gb``
-asks the memory planner (``repro.scale.plan_microbatch``) to pick the
-smallest M whose compiled step fits that per-device budget instead.
+repro.scale knobs: ``--precision`` picks the policy (f32/bf16/f16) and
+the model's activation dtype with it, ``--microbatch`` forces an
+accumulation factor, and ``--hbm-budget-gb`` asks the memory planner
+(``repro.scale.plan_microbatch``) to pick the smallest M whose compiled
+step fits that per-device budget instead.
+
+``main(argv)`` is also the library entry point: it returns the built
+``Trainer`` and the logged metric rows (``chip_smoke.py`` drives it).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,11 +37,12 @@ import numpy as np
 from repro import api, configs, data, scale
 from repro import obs as obs_mod
 from repro.core import available_methods, problems
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import Model
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -46,7 +55,6 @@ def main():
     ap.add_argument("--meta-lr", type=float, default=1e-3)
     ap.add_argument("--manual-collectives", action="store_true",
                     help="use the paper's single-sync shard_map schedule")
-    ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--precision", default="f32", choices=sorted(scale.POLICIES),
@@ -63,24 +71,35 @@ def main():
     ap.add_argument("--chrome-trace", default=None, metavar="PATH",
                     help="write a chrome://tracing file of the per-phase "
                          "span profile")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    # All reporting flows through one obs pipeline: the ConsoleSink keeps
-    # stdout identical to the pre-obs prints; --obs-log adds the durable
-    # JSONL the report CLI consumes.
-    obs = obs_mod.make_obs(log_path=args.obs_log, console=True,
-                           run_id=f"train-{args.arch}-{args.method}")
-    obs_mod.set_default(obs)
 
+@dataclasses.dataclass
+class Trainer:
+    """What ``build`` assembles: the config, mesh, model and learner (its
+    state initialised from the fixed seeds), and the seeded batch maker."""
+
+    cfg: Any
+    mesh: Any
+    model: Model
+    learner: api.MetaLearner
+    make_batch: Callable[..., Dict[str, jnp.ndarray]]
+    n_params: int
+
+
+def build(args: argparse.Namespace, obs) -> Trainer:
+    scale_cfg = scale.ScaleConfig(policy=args.precision, microbatch=args.microbatch)
     cfg = configs.get_smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
-    mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
+    # the activations follow --precision: bf16/f16 policies compute in
+    # 16 bits end to end (every shipped config sets f32 activations)
+    cfg = cfg.replace(dtype=scale_cfg.resolve().compute_dtype)
+    mesh = make_host_mesh()
     model = Model(cfg)
 
     spec = problems.make_data_optimization_spec(
         model.classifier_per_example if cfg.family == "encoder" else model.per_example,
         reweight=True,
     )
-    scale_cfg = scale.ScaleConfig(policy=args.precision, microbatch=args.microbatch)
     learner_args = dict(
         base_opt="adam", base_lr=args.base_lr,
         meta_opt="adam", meta_lr=args.meta_lr,
@@ -142,17 +161,38 @@ def main():
             learner = api.MetaLearner(spec, scale=scale_cfg, **learner_args)
             learner.init(theta, lam)
 
-    n_params = model.num_params(theta)
+    return Trainer(cfg=cfg, mesh=mesh, model=model, learner=learner,
+                   make_batch=make_batch, n_params=model.num_params(theta))
+
+
+def main(argv: Optional[Sequence[str]] = None) -> Tuple[Trainer, List[Dict[str, float]]]:
+    """Parse ``argv``, build, train. Returns the Trainer and one host
+    metric row per logged step (unrounded; ``step_s`` is that step's
+    seconds from dispatch until its metrics are on the host)."""
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    # All reporting flows through one obs pipeline: the ConsoleSink keeps
+    # stdout identical to the pre-obs prints; --obs-log adds the durable
+    # JSONL the report CLI consumes.
+    obs = obs_mod.make_obs(log_path=args.obs_log, console=True,
+                           run_id=f"train-{args.arch}-{args.method}")
+    obs_mod.set_default(obs)
+
+    tr = build(args, obs)
+    cfg, mesh, learner, make_batch = tr.cfg, tr.mesh, tr.learner, tr.make_batch
+    n_params = tr.n_params
+    microbatch = learner.cfg.scale.microbatch
     mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
     obs.emit("run", "run_start", data={
         "cli": "train", "arch": cfg.name, "method": args.method,
         "steps": args.steps, "unroll": args.unroll, "params": n_params,
         "schedule": learner.schedule, "precision": args.precision,
-        "microbatch": scale_cfg.microbatch, "mesh": mesh_shape})
+        "microbatch": microbatch, "mesh": mesh_shape})
     obs.log("run_header",
             f"arch={cfg.name} params={n_params:,} method={args.method} "
             f"schedule={learner.schedule} precision={args.precision} "
-            f"microbatch={scale_cfg.microbatch} mesh={mesh_shape}")
+            f"microbatch={microbatch} mesh={mesh_shape}")
 
     if args.obs_log or args.chrome_trace:
         # One eager step under the span tracer: real per-phase wall times
@@ -168,16 +208,19 @@ def main():
                     f"chrome trace ({len(spans)} spans) written to "
                     f"{args.chrome_trace}", path=args.chrome_trace)
 
+    rows: List[Dict[str, float]] = []
     t0 = time.time()
     for i in range(args.steps):
         base = make_batch(args.batch, args.unroll)
         meta = make_batch(max(args.batch // 2, 1))
+        t_step = time.perf_counter()
         metrics = learner.step(base, meta)
         if i % args.log_every == 0 or i == args.steps - 1:
             # one packed D2H read for the whole metric dict, then the same
             # greppable JSON line the CLI always printed (ConsoleSink)
-            row = {k: round(v, 4)
-                   for k, v in obs_mod.packed_read(metrics).items()}
+            host = obs_mod.packed_read(metrics)
+            rows.append(dict(host, step=i, step_s=time.perf_counter() - t_step))
+            row = {k: round(v, 4) for k, v in host.items()}
             row["elapsed_s"] = round(time.time() - t0, 1)
             obs.observe_step(i, row)
 
@@ -198,6 +241,7 @@ def main():
         "elapsed_s": round(time.time() - t0, 1), "steps": args.steps,
         "health": obs.health.status, "ring_dropped": obs.sink_dropped()})
     obs.close()
+    return tr, rows
 
 
 if __name__ == "__main__":

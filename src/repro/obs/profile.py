@@ -27,7 +27,7 @@ instruction's cost to the *innermost* phase on its op_name path:
 Joining with measured per-phase wall time (``Tracer.runtime_spans()``
 from ``MetaLearner.phase_profile()``) turns the static counts into
 achieved FLOP/s and utilization against the roofline peak
-(``roofline.analysis.PEAK_FLOPS`` by default).
+(the roofline table's peak for the target chip by default).
 
 The result dict is the optional ``attribution`` section of a
 ``PerfRecord`` (schema v1, additive — ``perf.record.validate_attribution``)
@@ -46,7 +46,7 @@ import json
 import re
 import sys
 from collections import defaultdict
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 from repro.roofline import hlo_parse
 from repro.obs.trace import PHASES
@@ -61,6 +61,7 @@ _INSTR_RE = re.compile(r"^\s*(ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
 _OPCODE_RE = re.compile(r"([a-zA-Z][\w\-]*)\(")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _SRC_RE = re.compile(r'source_file="([^"]*)"')
+_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
 _LHS_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _WINDOW_SIZE_RE = re.compile(r"window=\{[^}]*size=([0-9x]+)")
 _DIM_LABELS_RE = re.compile(r"dim_labels=\w+_(\w+)->")
@@ -111,6 +112,11 @@ class Instr:
         m = _SRC_RE.search(self.attr_text)
         return m.group(1) if m else ""
 
+    @property
+    def stack_frame_id(self) -> Optional[int]:
+        m = _FRAME_RE.search(self.attr_text)
+        return int(m.group(1)) if m else None
+
 
 def _split_type(rest: str) -> Tuple[str, str]:
     """Split ``f32[8,4]{1,0} add(...)`` (or a tuple type) into
@@ -159,6 +165,15 @@ def parse_instructions(lines: Iterable[str]) -> List[Instr]:
             operand_text=rem[mo.end():end], attr_text=rem[end:],
             is_root=bool(m.group(1)),
         ))
+    # current XLA prints operands as bare names (``dot(%a, %b)``); give
+    # each its defining instruction's type, as older XLA printed it, so
+    # contraction sizes and operand bytes stay readable
+    types = {ins.name: ins.type_text for ins in out}
+    for ins in out:
+        refs = _OPERAND_REF_RE.findall(ins.operand_text)
+        if refs and not hlo_parse._SHAPE_RE.search(ins.operand_text):
+            ins.operand_text = ", ".join(f"{types.get(r, '')} %{r}".lstrip()
+                                         for r in refs)
     return out
 
 
@@ -225,6 +240,69 @@ def _module_of(source_file: str) -> Optional[str]:
     return source_file.rsplit("/", 1)[-1] if source_file else None
 
 
+def _proto_fields(buf: bytes) -> Iterator[Tuple[int, Any]]:
+    """(field number, value) pairs of one serialized protobuf message:
+    varints as ints, length-delimited fields as bytes."""
+
+    def varint(i):
+        out = shift = 0
+        while True:
+            b = buf[i]
+            out |= (b & 0x7F) << shift
+            shift += 7
+            i += 1
+            if b < 0x80:
+                return out, i
+
+    i = 0
+    while i < len(buf):
+        key, i = varint(i)
+        num, wire = key >> 3, key & 7
+        if wire == 0:
+            val, i = varint(i)
+        elif wire == 2:
+            n, i = varint(i)
+            val, i = buf[i:i + n], i + n
+        elif wire in (1, 5):
+            n = 8 if wire == 1 else 4
+            val, i = buf[i:i + n], i + n
+        else:
+            raise ValueError(f"unsupported protobuf wire type {wire}")
+        yield num, val
+
+
+def stack_frame_files(compiled: Any) -> Dict[int, str]:
+    """``stack_frame_id`` -> source file, for one compiled module.
+
+    Current JAX writes ``stack_frame_id=N`` into each instruction's
+    metadata instead of ``source_file=``. The ids index the module's
+    stack-frame table (``HloModuleProto.stack_frame_index``, field 17),
+    which the printed text leaves out, so it is read from the serialized
+    module proto. Frame N is the innermost user frame of the op; its
+    ``file_location_id`` and that location's ``file_name_id`` are
+    1-based."""
+
+    buf = compiled.runtime_executable().hlo_modules()[0].as_serialized_hlo_module_proto()
+    files: List[str] = []
+    loc_file: List[int] = []
+    frame_loc: List[int] = []
+    for num, val in _proto_fields(buf):
+        if num != 17:
+            continue
+        for n2, v2 in _proto_fields(val):
+            if n2 == 1:
+                files.append(v2.decode())
+            elif n2 == 3:
+                loc_file.append(dict(_proto_fields(v2)).get(1, 0))
+            elif n2 == 4:
+                frame_loc.append(dict(_proto_fields(v2)).get(1, 0))
+    out = {}
+    for fid, loc in enumerate(frame_loc, start=1):
+        if 0 < loc <= len(loc_file) and 0 < loc_file[loc - 1] <= len(files):
+            out[fid] = files[loc_file[loc - 1] - 1]
+    return out
+
+
 def _collective_opcode(op: str) -> Optional[str]:
     if op.endswith("-start"):
         op = op[: -len("-start")]
@@ -288,10 +366,12 @@ def attribute(compiled_or_text: Any, *, phases: Optional[Sequence[str]] = None,
 
     text = (compiled_or_text if isinstance(compiled_or_text, str)
             else compiled_or_text.as_text())
+    frame_files = (stack_frame_files(compiled_or_text)
+                   if hasattr(compiled_or_text, "runtime_executable") else {})
     phases = tuple(phases) if phases is not None else DEFAULT_PHASES
     if peak_flops is None:
-        from repro.roofline.analysis import PEAK_FLOPS
-        peak_flops = PEAK_FLOPS
+        from repro.roofline.analysis import TARGET_DEVICE_KIND, device_peaks
+        peak_flops = device_peaks(TARGET_DEVICE_KIND).flops
 
     comps = hlo_parse.split_computations(text)
     mult = hlo_parse.computation_multipliers(comps, follow_calls=True)
@@ -326,7 +406,8 @@ def attribute(compiled_or_text: Any, *, phases: Optional[Sequence[str]] = None,
                 bucket["collective_bytes"] += ins.out_bytes * m
                 bucket["collective_count"] += m
             if flops:
-                mod = _module_of(ins.source_file)
+                mod = _module_of(ins.source_file
+                                 or frame_files.get(ins.stack_frame_id, ""))
                 if mod:
                     per_module[mod] += flops
 

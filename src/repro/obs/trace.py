@@ -73,11 +73,8 @@ class Span:
 
 
 def _in_jax_trace() -> bool:
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax absent or API moved
-        return False
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class Tracer:

@@ -44,7 +44,7 @@ class Tolerance:
     memory_ratio: float = 1.15
     collective_bytes_ratio: float = 1.10
     #: per-phase attributed FLOPs are deterministic given the jax pin
-    #: (perf-gate CI pins it); 10% absorbs compiler-churn refusion only
+    #: (requirements.txt pins it); 10% absorbs compiler-churn refusion only
     attribution_flops_ratio: float = 1.10
 
 

@@ -1,12 +1,13 @@
-"""Roofline analysis from compiled dry-run artifacts (TPU v5e constants)."""
+"""Roofline analysis from compiled dry-run artifacts (per-device peaks
+keyed by ``device_kind``)."""
 
 from repro.roofline.analysis import (
-    HBM_BW,
-    HBM_BYTES,
-    ICI_BW,
-    PEAK_FLOPS,
+    DEVICE_PEAKS,
+    TARGET_DEVICE_KIND,
+    DevicePeaks,
     Roofline,
     analyze,
+    device_peaks,
     forward_flops,
     param_counts,
     step_bytes,
@@ -15,7 +16,7 @@ from repro.roofline.analysis import (
 from repro.roofline.hlo_parse import collective_stats
 
 __all__ = [
-    "HBM_BW", "HBM_BYTES", "ICI_BW", "PEAK_FLOPS",
-    "Roofline", "analyze", "collective_stats", "forward_flops",
+    "DEVICE_PEAKS", "TARGET_DEVICE_KIND", "DevicePeaks",
+    "Roofline", "analyze", "collective_stats", "device_peaks", "forward_flops",
     "param_counts", "step_bytes", "step_flops",
 ]

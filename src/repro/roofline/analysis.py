@@ -19,8 +19,8 @@ Measurement methodology (see EXPERIMENTS.md §Method):
 * compute/memory terms assume ideal sharding (global / chips); the HLO is
   the structural witness that the program actually partitions.
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, 16 GiB HBM,
-~50 GB/s/link ICI.
+Hardware: per-chip peaks come from ``DEVICE_PEAKS``, keyed by
+``jax.Device.device_kind``; the dry run plans for ``TARGET_DEVICE_KIND``.
 """
 
 from __future__ import annotations
@@ -32,10 +32,36 @@ import jax
 
 from repro.roofline import hlo_parse
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes / s / chip
-ICI_BW = 50e9  # bytes / s / link
-HBM_BYTES = 16 * 2**30  # v5e HBM capacity
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # interconnect bytes/s per link
+    hbm_bytes: int  # HBM capacity per chip
+
+
+#: Published per-chip peaks keyed by ``device_kind``. Source: Google Cloud
+#: documentation, "TPU v5e" system architecture: 197 TFLOP/s bf16, 16 GB
+#: HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (4 links of
+#: 50 GB/s each).
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                               hbm_bytes=16 * 2**30),
+}
+
+#: The chip the dry run's production mesh is made of.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; an unknown device raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}") from None
+
 
 ACT_BYTES = 2  # bf16 activations
 LOGIT_BYTES = 4  # f32 logits
@@ -238,7 +264,9 @@ def cost_analysis_dict(compiled) -> Dict[str, float]:
 
 
 def analyze(name: str, compiled, hlo_text: str, cfg, shape, kind: str,
-            param_shapes, n_devices: int, cache_shapes=None) -> Roofline:
+            param_shapes, n_devices: int, cache_shapes=None,
+            device_kind: str = TARGET_DEVICE_KIND) -> Roofline:
+    peaks = device_peaks(device_kind)
     counts = param_counts(param_shapes)
     cache_bytes = 0
     if cache_shapes is not None:
@@ -252,9 +280,9 @@ def analyze(name: str, compiled, hlo_text: str, cfg, shape, kind: str,
     mem = step_bytes(cfg, counts, shape, kind, cache_bytes)
     coll = hlo_parse.collective_stats(hlo_text)
 
-    compute_s = flops / (n_devices * PEAK_FLOPS)
-    memory_s = mem / (n_devices * HBM_BW)
-    collective_s = coll["total_bytes"] / ICI_BW
+    compute_s = flops / (n_devices * peaks.flops)
+    memory_s = mem / (n_devices * peaks.hbm_bw)
+    collective_s = coll["total_bytes"] / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
 

@@ -15,7 +15,7 @@ This parser:
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -31,6 +31,9 @@ _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\]")
 _COMP_HEADER = re.compile(r"^\s*(?:ENTRY\s+)?%([^\s(]+)\s*\(.*\)\s*->.*\{\s*$")
 _WHILE_RE = re.compile(r"while\(.*?\).*?body=%([^\s,]+)")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_COND_RE = re.compile(r"condition=%([^\s,]+)")
+_S32_CONST_RE = re.compile(r"%([\w.\-]+) = s32\[\]\S* constant\((\d+)\)")
+_LT_ROOT_RE = re.compile(r"ROOT .* compare\(([^)]*)\).*direction=LT")
 #: every way one computation invokes another in HLO text: loop body /
 #: condition, fusion/call targets, reducer lambdas, conditional branches
 _CALLEE_RE = re.compile(
@@ -75,6 +78,21 @@ def split_computations(text: str) -> Dict[str, List[str]]:
     return comps
 
 
+def _condition_trip(lines: List[str]) -> Optional[int]:
+    """Trip count of a loop whose condition is ``counter < constant`` —
+    how a scan lowers; its counter starts at 0. The TPU compiler drops
+    the ``known_trip_count`` annotation that the CPU compiler keeps."""
+
+    consts = dict(m.groups() for m in map(_S32_CONST_RE.search, lines) if m)
+    for line in lines:
+        m = _LT_ROOT_RE.search(line)
+        if m:
+            for operand in re.findall(r"%([\w.\-]+)", m.group(1)):
+                if operand in consts:
+                    return int(consts[operand])
+    return None
+
+
 def computation_multipliers(comps: Dict[str, List[str]],
                             follow_calls: bool = False) -> Dict[str, float]:
     """Multiplier per computation = product of enclosing loop trip counts.
@@ -98,7 +116,10 @@ def computation_multipliers(comps: Dict[str, List[str]],
                 mb = _WHILE_RE.search(line)
                 if mb:
                     mt = _TRIP_RE.search(line)
-                    trip = int(mt.group(1)) if mt else 1
+                    mc = _COND_RE.search(line)
+                    trip = (int(mt.group(1)) if mt else
+                            (mc and _condition_trip(comps.get(mc.group(1), [])))
+                            or 1)
                     edges.setdefault(name, []).append((mb.group(1), trip))
             if not follow_calls:
                 continue

@@ -88,6 +88,16 @@ class PrecisionPolicy:
                 and self.param_jnp == jnp.float32
                 and not self.dynamic_scaling)
 
+    def matmul_context(self):
+        """The ``jax.default_matmul_precision`` a meta step traces under.
+        On a TPU, XLA's ``default`` multiplies f32 operands in one bf16
+        pass, which rounds SAMA's central-difference perturbation
+        theta +- eps*v away; an f32 compute dtype therefore asks for
+        ``highest`` (true f32 products). 16-bit compute keeps ``default``:
+        its operands are already 16-bit."""
+        return jax.default_matmul_precision(
+            "highest" if self.compute_jnp == jnp.float32 else "default")
+
 
 #: the built-in policies (DESIGN.md §11): f32 master params everywhere;
 #: bf16 computes unscaled (f32 exponent range), f16 computes under a

@@ -18,6 +18,7 @@ Plus the diff CLI: an injected phase regression must rank top.
 """
 
 import json
+import re
 import os
 import subprocess
 import sys
@@ -110,6 +111,19 @@ def test_synthetic_modules_and_top_sink():
     assert mods["mlp.py"]["flops"] == 384
     assert attr["top_module"] == "attention.py"
     assert mods["attention.py"]["flop_frac"] == pytest.approx(3584 / 4353)
+
+
+def test_operands_printed_without_types_resolve_to_defining_types():
+    # current XLA prints operands as bare names: ``dot(%a, %b)``. The
+    # contraction size and operand bytes then come from each operand's
+    # defining instruction, so the FLOP model reads the same numbers
+    bare = re.sub(r"(\([^()]*\)|[a-z0-9]+\[[0-9,]*\]) %", "%", SYN)
+    assert "dot(%fp0, %fp1)" in bare and "while(%t0)" in bare
+    a, b = profile_mod.attribute(SYN), profile_mod.attribute(bare)
+    for ph in ("base_unroll", "meta_pass", "cd_passes", "finalize"):
+        assert b["phases"][ph]["flops"] == a["phases"][ph]["flops"]
+    assert b["phases"]["meta_pass"]["bytes"] == a["phases"]["meta_pass"]["bytes"]
+    assert b["modules"] == a["modules"]
 
 
 def test_synthetic_collectives_charged_to_phase():
@@ -443,12 +457,12 @@ import jax.numpy as jnp
 from repro import configs, optim
 from repro.core import EngineConfig, init_state, problems
 from repro.launch import distributed as dist
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import Model
 from repro.obs import profile as profile_mod
 
 UNROLL = 2
-mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = configs.get_smoke_config("bert-base").replace(
     d_model=128, num_layers=2, num_labels=4, num_heads=2, num_kv_heads=2,
     head_dim=64, d_ff=256, remat=False)
@@ -524,12 +538,12 @@ from repro import configs, optim
 from repro.core import EngineConfig, init_state, problems
 from repro.kernels import dispatch
 from repro.launch import distributed as dist
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.models import Model
 from repro.obs import profile as profile_mod
 
 UNROLL = 2
-mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = configs.get_smoke_config("bert-base").replace(
     d_model=64, num_layers=1, num_labels=4, num_heads=2, num_kv_heads=2,
     head_dim=32, d_ff=128, remat=False)

@@ -348,7 +348,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import problems
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.dataopt import DataOptimizer, score_dataset
 from repro.dataopt.reweight import ReweightedIterator
 
@@ -367,7 +367,7 @@ rng = np.random.default_rng(0)
 train = {"x": rng.normal(size=(n, d)).astype(np.float32),
          "y": rng.integers(0, C, n).astype(np.int32)}
 
-mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 pe_1 = score_dataset(per_ex, theta, train, fields=("x", "y"), batch_size=16)
 pe_8 = score_dataset(per_ex, theta, train, fields=("x", "y"), batch_size=16, mesh=mesh)

@@ -85,3 +85,43 @@ ENTRY %main (a: f32[4]) -> f32[4] {{
     stats = hlo_parse.collective_stats(text)
     assert stats[f"{collective}_count"] == 1
     assert stats[f"{collective}_bytes"] == 16 * 4
+
+
+def test_device_peaks_keyed_by_device_kind():
+    from repro.roofline import analysis
+
+    v5e = analysis.device_peaks("TPU v5 lite")
+    assert v5e.flops == 197e12 and v5e.hbm_bw == 819e9
+    assert analysis.device_peaks(analysis.TARGET_DEVICE_KIND) is v5e
+    with pytest.raises(ValueError, match="cpu"):
+        analysis.device_peaks("cpu")
+
+
+def test_trip_count_read_from_loop_condition():
+    """A while without a known_trip_count annotation (as the TPU compiler
+    prints it) takes its trip count from a ``counter < constant``
+    condition, so collectives in its body are scaled."""
+    text = """\
+HloModule tpu_loop
+%cond (p: (s32[], f32[4])) -> pred[] {
+  %constant.7 = s32[]{:T(128)} constant(3)
+  %p = (s32[], f32[4]) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  ROOT %lt = pred[]{:T(512)} compare(%i, %constant.7), direction=LT
+}
+%body (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]) parameter(0)
+  %x = f32[4] get-tuple-element(%p), index=1
+  %ar = f32[4] all-reduce(%x), to_apply=%sum
+  %i = s32[] get-tuple-element(%p), index=0
+  ROOT %t = (s32[], f32[4]) tuple(%i, %ar)
+}
+ENTRY %main (a: f32[4]) -> f32[4] {
+  %a = f32[4] parameter(0)
+  %z = s32[] constant(0)
+  %t0 = (s32[], f32[4]) tuple(%z, %a)
+  %w = (s32[], f32[4]) while(%t0), condition=%cond, body=%body
+  ROOT %r = f32[4] get-tuple-element(%w), index=1
+}
+"""
+    assert hlo_parse.collective_stats(text)["all-reduce_count"] == 3

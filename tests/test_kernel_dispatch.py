@@ -1,7 +1,7 @@
 """The kernel backend-dispatch registry (DESIGN.md §10, docs/kernels.md).
 
 Covers the registry semantics, the selection precedence (explicit backend >
-$REPRO_KERNEL_BACKEND > platform default), safe fallback for unavailable /
+$REPRO_KERNEL_BACKEND > platform default), safe fallback for
 ineligible backends, a parity sweep of EVERY registered kernel against its
 ref.py oracle on every backend available on CPU CI (pallas-interpret + ref)
 including ragged/non-tile-aligned shapes, and the ISSUE acceptance pins:
@@ -144,21 +144,24 @@ def test_weighted_ce_parity(shape, backend):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.skipif(jax.default_backend() == "tpu", reason="CPU/GPU-only fallback")
+@pytest.mark.skipif(jax.default_backend() == "tpu", reason="needs a non-TPU backend")
 def test_forced_pallas_tpu_falls_back_safely(monkeypatch):
-    """Forcing the compiled-TPU backend on a host without a TPU must degrade
-    to ref (with the fallback recorded), never crash in lowering."""
+    """Forcing the compiled-TPU backend on a host without a TPU raises,
+    naming the kernel and the backend actually present, instead of quietly
+    running ``ref`` in its place; nothing is logged as dispatched."""
 
     monkeypatch.setenv(dispatch.ENV_VAR, "pallas-tpu")
     g, m, gm = _flat_case(64, 3)
     v = jnp.abs(gm)
-    out, _ = dispatch.get_kernel("adam_adapt")(g, m, v, gm, t=1, b1=0.9, b2=0.999,
-                                               eps=1e-8, lr=1.0)
-    out_r, _ = ref.adam_adapt_product(g, m, v, gm, t=1, b1=0.9, b2=0.999, eps=1e-8, lr=1.0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_r), rtol=1e-5, atol=1e-7)
-    kernel, backend, reason = dispatch.dispatch_log()[-1]
-    assert (kernel, backend) == ("adam_adapt", "ref")
-    assert "pallas-tpu:unavailable" in reason
+    dispatch.clear_dispatch_log()
+    with pytest.raises(RuntimeError, match=r"'adam_adapt'.*'pallas-tpu'.*not 'tpu'"):
+        dispatch.get_kernel("adam_adapt")(g, m, v, gm, t=1, b1=0.9, b2=0.999,
+                                          eps=1e-8, lr=1.0)
+    assert dispatch.dispatch_log() == []
+    # an explicit backend= is the same forced choice
+    with pytest.raises(RuntimeError, match="pallas-tpu"):
+        dispatch.get_kernel("weighted_ce", backend="pallas-tpu")(
+            jnp.zeros((8, 128)), jnp.zeros((8,), jnp.int32))
 
 
 def test_ineligible_shape_falls_back():
@@ -336,10 +339,9 @@ from repro import optim, perf
 from repro.core import EngineConfig, init_state, problems
 from repro.kernels import dispatch
 from repro.launch import distributed as dist
-from repro.launch.mesh import make_mesh
 
 UNROLL = 2
-mesh = make_mesh((8, 1), ("data", "model"))
+mesh = jax.make_mesh((8, 1), ("data", "model"))
 
 def apply_fn(theta, x):
     return jnp.tanh(x @ theta["w1"]) @ theta["w2"]

@@ -76,9 +76,9 @@ import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.roofline import hlo_parse
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 L, B, D = 8, 16, 64
 def f(x, ws):
     def body(c, w):
@@ -126,3 +126,49 @@ def _run_subprocess(script: str, timeout: int = 300) -> str:
                          text=True, env=env, cwd=root, timeout=timeout)
     assert out.returncode == 0, out.stderr[-2000:]
     return out.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_dir_from_env_or_fixed_in_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is used and nothing is
+    configured; otherwise the cache goes to <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert got == str(repo / ".jax_cache") == jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_train_main_returns_rows_and_builds_mesh_from_devices():
+    """``train.main(argv)`` is the library entry point: it returns the
+    Trainer and one unrounded row per logged step, and its mesh spans
+    every device present, (data=n, model=1)."""
+    import math
+
+    from repro import obs as obs_mod
+    from repro.launch import train
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        tr, rows = train.main(["--arch", "bert-base", "--smoke", "--steps", "2",
+                               "--log-every", "1", "--batch", "4", "--seq", "16"])
+    finally:
+        obs_mod.set_default(obs_mod.NULL_OBS)
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert dict(zip(tr.mesh.axis_names, tr.mesh.devices.shape)) == {
+        "data": len(jax.devices()), "model": 1}
+    assert [r["step"] for r in rows] == [0, 1]
+    for r in rows:
+        assert r["step_s"] > 0
+        assert all(math.isfinite(r[k]) for k in ("base_loss", "meta_loss",
+                                                  "hypergrad_norm", "eps"))
+    assert rows[0]["eps"] > 0  # unrounded (the console line rounds to 4 places)

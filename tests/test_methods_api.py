@@ -226,10 +226,9 @@ import numpy as np
 from repro import optim
 from repro.core import EngineConfig, init_state, problems, methods
 from repro.launch import distributed as dist
-from repro.launch.mesh import make_mesh
 from repro.roofline import hlo_parse
 
-mesh = make_mesh((8, 1), ("data", "model"))
+mesh = jax.make_mesh((8, 1), ("data", "model"))
 
 def apply_fn(theta, x):
     return jnp.tanh(x @ theta["w1"]) @ theta["w2"]
@@ -320,11 +319,10 @@ import numpy as np
 from repro import optim
 from repro.core import EngineConfig, init_state, problems
 from repro.launch import distributed as dist
-from repro.launch.mesh import make_mesh
 
 # model axis LIVE (4 data x 2 model): the bucket must fall back to the
 # per-leaf reduce so tensor-parallel sharding survives.
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"))
 
 def apply_fn(theta, x):
     return jnp.tanh(x @ theta["w1"]) @ theta["w2"]
@@ -351,13 +349,6 @@ print(json.dumps({"finite": all(np.isfinite(float(v)) for v in metrics.values())
 """
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax 0.4.x partial-manual shard_map + lax.scan aborts in the XLA "
-           "partitioner (hlo_sharding_util IsManualSubgroup check) on meshes "
-           "with a live auto axis — pre-existing version limitation, the "
-           "per-leaf bucket path is exercised on modern jax",
-)
 def test_manual_step_with_live_model_axis():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"

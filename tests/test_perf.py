@@ -346,10 +346,9 @@ import jax.numpy as jnp
 from repro import optim, perf
 from repro.core import EngineConfig, init_state, problems
 from repro.launch import distributed as dist
-from repro.launch.mesh import make_mesh
 
 UNROLL = 2
-mesh = make_mesh((8, 1), ("data", "model"))
+mesh = jax.make_mesh((8, 1), ("data", "model"))
 
 def apply_fn(theta, x):
     return jnp.tanh(x @ theta["w1"]) @ theta["w2"]

@@ -35,10 +35,10 @@ import numpy as np
 from repro import optim
 from repro.core import EngineConfig, init_state, make_meta_step, problems
 from repro.launch import distributed as dist
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.scale import ScaleConfig
 
-mesh = make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 def apply_fn(theta, x):
     return jnp.tanh(x @ theta["w1"]) @ theta["w2"]
