@@ -11,7 +11,7 @@ Arms:
 * ``attribution_sama``   — the WRENCH-analog mini-BERT SAMA step (the
   bench_throughput_memory configuration): full ``perf.profile_step``
   with ``attribution=True`` plus measured per-phase wall times from one
-  eager step under the span tracer (the phase_profile protocol), so the
+  eager (un-jitted) step under the span tracer, so the
   record carries achieved-vs-roofline utilization per phase.
 * ``attribution_manual`` — the manual single-sync schedule on 8 forced
   host devices (subprocess, same harness as bench_obs): attribution of

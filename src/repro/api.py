@@ -38,6 +38,7 @@ Typical use::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
@@ -124,8 +125,10 @@ class MetaLearner:
         else:
             step = make_meta_step(self.spec, self.base_opt, self.meta_opt, self.cfg)
         self.schedule = schedule
-        self._raw_step = step  # un-jitted: phase_profile runs it eagerly
         self.step_fn = jax.jit(step) if jit else step
+        # host-side count of dispatched steps: the profiler's step marker
+        # reads it, where reading state.step would sync with the device
+        self._dispatched = 0
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -135,19 +138,28 @@ class MetaLearner:
         LossScaleState from ``cfg.scale``)."""
         self.state = init_state(theta, lam, self.base_opt, self.meta_opt,
                                 scale=self.cfg.scale)
+        self._dispatched = 0
         return self.state
 
     def step(self, base_batches, meta_batch) -> Dict[str, Any]:
         """One meta step: K base updates + one meta update. Advances
-        ``self.state`` and returns the metric dict (jax scalars)."""
+        ``self.state`` and returns the metric dict (jax scalars).
+
+        The dispatch runs under ``jax.profiler.StepTraceAnnotation(
+        "meta_step", step_num=n)``, so a profile marks each step. ``n`` is
+        counted on the host, from 0 at ``init`` or from the restored step
+        at ``load``: nothing is read back from the device."""
 
         if self.state is None:
             raise RuntimeError("call init(theta, lam) or load(...) before step()")
-        if self.mesh is not None:
-            with self.mesh:
+        n = self._dispatched
+        self._dispatched += 1
+        with jax.profiler.StepTraceAnnotation("meta_step", step_num=n):
+            if self.mesh is not None:
+                with self.mesh:
+                    self.state, metrics = self.step_fn(self.state, base_batches, meta_batch)
+            else:
                 self.state, metrics = self.step_fn(self.state, base_batches, meta_batch)
-        else:
-            self.state, metrics = self.step_fn(self.state, base_batches, meta_batch)
         return metrics
 
     def fit(
@@ -197,9 +209,9 @@ class MetaLearner:
 
         ``attribution=True`` additionally partitions the compiled step's
         FLOPs/bytes/collectives by engine phase (``repro.obs.profile``)
-        into the record's ``attribution`` section; pass the spans from
-        ``phase_profile`` as ``attribution_spans`` to join measured wall
-        time and roofline utilization per phase.
+        into the record's ``attribution`` section; ``attribution_spans``
+        (measured ``repro.obs.Span`` objects or dicts named by phase)
+        joins wall time and roofline utilization per phase.
 
         Always profiles the JIT-COMPILED step (memory/collective accounting
         needs the compiled executable) — for a ``jit=False`` learner these
@@ -228,35 +240,6 @@ class MetaLearner:
                                  repeats=repeats, extra=extra,
                                  attribution=attribution,
                                  attribution_spans=attribution_spans)
-
-    def phase_profile(self, base_batches, meta_batch):
-        """Per-phase host wall times: run ONE step eagerly (un-jitted)
-        under an activated span tracer, so the engine's phase annotations
-        (base unroll, meta pass, CD passes, finalize, meta update, and the
-        flat-bucket all-reduce on the manual schedule) record real
-        execution spans instead of jit trace-time. Returns the list of
-        ``repro.obs.Span``; when the learner carries an enabled obs, each
-        span is also emitted as a ``span`` event.
-
-        The state is NOT advanced and the jitted step's cache is
-        untouched. Eager per-op dispatch overhead inflates absolute
-        numbers — read the result as the *relative* cost of the phases
-        (``repro.perf`` owns absolute step timing)."""
-
-        from repro import obs as obs_mod
-
-        if self.state is None:
-            raise RuntimeError(
-                "call init(theta, lam) or load(...) before phase_profile()")
-        tracer = obs_mod.Tracer(obs=self.obs if self.obs.enabled else None)
-        with obs_mod.activate(tracer):
-            if self.mesh is not None:
-                with self.mesh:
-                    out = self._raw_step(self.state, base_batches, meta_batch)
-            else:
-                out = self._raw_step(self.state, base_batches, meta_batch)
-            jax.block_until_ready(out)
-        return tracer.runtime_spans()
 
     def verify_census(self, base_batches, meta_batch):
         """Compile the step on these example shapes and check the
@@ -290,10 +273,12 @@ class MetaLearner:
 
     # -- checkpointing -----------------------------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="checkpoint")
     def save(self, path: Optional[str] = None, *, meta: Optional[Dict[str, Any]] = None) -> str:
         """Checkpoint the full EngineState. Default path:
         ``{checkpoint_dir}/step_{NNNNNN}``. ``meta`` entries are merged into
-        the manifest alongside the learner's own (method/unroll/schedule)."""
+        the manifest alongside the learner's own (method/unroll/schedule).
+        A profile shows the save as the host span ``checkpoint``."""
 
         if self.state is None:
             raise RuntimeError("nothing to save: no state")
@@ -341,6 +326,7 @@ class MetaLearner:
                     "(or restore via repro.checkpoint directly to override)"
                 )
         self.state = state
+        self._dispatched = int(state.step)
         if self.obs.enabled:
             self.obs.emit("checkpoint", "restore", step=int(state.step),
                           data={"path": path})

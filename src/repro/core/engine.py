@@ -336,15 +336,21 @@ def run_loop(step_fn, state, batch_iter, num_steps: int, log_every: int = 0,
     transfer per logged step instead of one blocking ``float(v)`` per
     key. ``obs`` (a ``repro.obs.Obs``) receives the same host dict via
     ``observe_step`` at the same boundary, so observability adds no sync
-    points to the hot loop; ``obs=None`` logs nothing extra."""
+    points to the hot loop; ``obs=None`` logs nothing extra.
+
+    The host work between steps is named for ``jax.profiler``:
+    ``next_batch`` around pulling the batches and ``log_read`` around the
+    metric read."""
 
     history = []
     for i in range(num_steps):
-        base_batches, meta_batch = next(batch_iter)
+        with jax.profiler.TraceAnnotation("next_batch"):
+            base_batches, meta_batch = next(batch_iter)
         state, metrics = step_fn(state, base_batches, meta_batch)
         if log_every and (i % log_every == 0 or i == num_steps - 1):
-            row = {k: float(v)
-                   for k, v in obs_metrics.packed_read(metrics).items()}
+            with jax.profiler.TraceAnnotation("log_read"):
+                host = obs_metrics.packed_read(metrics)
+            row = {k: float(v) for k, v in host.items()}
             history.append(row | {"step": i})
             if obs is not None and obs.enabled:
                 obs.observe_step(i, row)

@@ -21,9 +21,15 @@ def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; return its directory.
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    nothing else is configured here. Otherwise the cache goes to
-    ``DEFAULT_DIR``. ``LIBTPU_INIT_ARGS`` is never touched."""
+    no other directory is configured here. Otherwise the cache goes to
+    ``DEFAULT_DIR``. ``LIBTPU_INIT_ARGS`` is never touched.
 
+    Either way the ops' metadata joins the cache key. JAX leaves it out by
+    default, so a program compiled before a ``jax.named_scope`` was added
+    or renamed would be loaded with its old op names, and a profile of it
+    would charge its device time to scopes the code no longer has."""
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

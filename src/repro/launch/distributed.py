@@ -191,9 +191,12 @@ def make_manual_step(
         """The per-base-step DDP sync: one bucketed pmean over the data
         axes, sub-f32 leaves promoted for the collective and restored
         after. With microbatch accumulation this runs on the ACCUMULATED
-        gradient — one all-reduce per base step for every M."""
+        gradient — one all-reduce per base step for every M. Named
+        ``grad_sync`` in the compiled ops' metadata, as the meta bucket's
+        exchange is ``allreduce_flat``."""
 
-        g_red = bucket_pmean(cast_for_reduce(g_loc), dp)
+        with jax.named_scope("grad_sync"):
+            g_red = bucket_pmean(cast_for_reduce(g_loc), dp)
         return jax.tree_util.tree_map(lambda r, gl: r.astype(gl.dtype), g_red, g_loc)
 
     def local_step(state: EngineState, base_batches, meta_batch):

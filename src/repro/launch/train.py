@@ -19,6 +19,11 @@ accumulation factor, and ``--hbm-budget-gb`` asks the memory planner
 (``repro.scale.plan_microbatch``) to pick the smallest M whose compiled
 step fits that per-device budget instead.
 
+``--profile-dir DIR`` writes a ``jax.profiler`` capture of three jitted
+steps after the second (which may still compile): the device ops under
+their phase and block scopes, each step's ``meta_step`` marker, and the
+host spans ``next_batch`` and ``log_read``.
+
 ``main(argv)`` is also the library entry point: it returns the built
 ``Trainer`` and the logged metric rows (``chip_smoke.py`` drives it).
 """
@@ -26,6 +31,7 @@ step fits that per-device budget instead.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -40,6 +46,9 @@ from repro.core import available_methods, problems
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import Model
+
+#: the steps a --profile-dir capture holds: three after the first two
+PROFILED = (2, 3, 4)
 
 
 def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
@@ -68,10 +77,13 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--obs-log", default=None, metavar="PATH",
                     help="append structured events (JSONL) for "
                          "`python -m repro.obs.report`")
-    ap.add_argument("--chrome-trace", default=None, metavar="PATH",
-                    help="write a chrome://tracing file of the per-phase "
-                         "span profile")
-    return ap.parse_args(argv)
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of steps "
+                         f"{PROFILED[0]}-{PROFILED[-1]} (0-based) under DIR")
+    args = ap.parse_args(argv)
+    if args.profile_dir and args.steps <= PROFILED[-1]:
+        ap.error(f"--profile-dir needs --steps > {PROFILED[-1]}")
+    return args
 
 
 @dataclasses.dataclass
@@ -194,35 +206,34 @@ def main(argv: Optional[Sequence[str]] = None) -> Tuple[Trainer, List[Dict[str, 
             f"schedule={learner.schedule} precision={args.precision} "
             f"microbatch={microbatch} mesh={mesh_shape}")
 
-    if args.obs_log or args.chrome_trace:
-        # One eager step under the span tracer: real per-phase wall times
-        # for the report / chrome trace. A dedicated RNG keeps the training
-        # data stream identical to an un-profiled run; state is untouched.
-        prof_rng = np.random.default_rng(2 ** 20)
-        spans = learner.phase_profile(
-            make_batch(args.batch, args.unroll, rng=prof_rng),
-            make_batch(max(args.batch // 2, 1), rng=prof_rng))
-        if args.chrome_trace:
-            obs_mod.write_chrome_trace(args.chrome_trace, spans)
-            obs.log("chrome_trace",
-                    f"chrome trace ({len(spans)} spans) written to "
-                    f"{args.chrome_trace}", path=args.chrome_trace)
-
     rows: List[Dict[str, float]] = []
     t0 = time.time()
-    for i in range(args.steps):
-        base = make_batch(args.batch, args.unroll)
-        meta = make_batch(max(args.batch // 2, 1))
-        t_step = time.perf_counter()
-        metrics = learner.step(base, meta)
-        if i % args.log_every == 0 or i == args.steps - 1:
-            # one packed D2H read for the whole metric dict, then the same
-            # greppable JSON line the CLI always printed (ConsoleSink)
-            host = obs_mod.packed_read(metrics)
-            rows.append(dict(host, step=i, step_s=time.perf_counter() - t_step))
-            row = {k: round(v, 4) for k, v in host.items()}
-            row["elapsed_s"] = round(time.time() - t0, 1)
-            obs.observe_step(i, row)
+    with contextlib.ExitStack() as capture:  # stops a capture cut short
+        for i in range(args.steps):
+            if args.profile_dir and i == PROFILED[0]:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0  # TraceMe spans, not every call
+                capture.enter_context(jax.profiler.trace(
+                    args.profile_dir, profiler_options=options))
+            with jax.profiler.TraceAnnotation("next_batch"):
+                base = make_batch(args.batch, args.unroll)
+                meta = make_batch(max(args.batch // 2, 1))
+            t_step = time.perf_counter()
+            metrics = learner.step(base, meta)
+            if i % args.log_every == 0 or i == args.steps - 1:
+                # one packed D2H read for the whole metric dict, then the same
+                # greppable JSON line the CLI always printed (ConsoleSink)
+                with jax.profiler.TraceAnnotation("log_read"):
+                    host = obs_mod.packed_read(metrics)
+                rows.append(dict(host, step=i, step_s=time.perf_counter() - t_step))
+                row = {k: round(v, 4) for k, v in host.items()}
+                row["elapsed_s"] = round(time.time() - t0, 1)
+                obs.observe_step(i, row)
+            if args.profile_dir and i == PROFILED[-1]:
+                jax.block_until_ready(metrics)
+                capture.close()
+                obs.log("profile", f"profile of steps {PROFILED[0]}-{i} written "
+                        f"under {args.profile_dir}", path=args.profile_dir)
 
     if args.manual_collectives and args.obs_log:
         census = learner.verify_census(base, meta)

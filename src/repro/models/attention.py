@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.kernels import dispatch
 from repro.models import common as cm
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -143,61 +144,62 @@ def self_attention(
     cache: Optional[Dict] = None,
     cache_pos=None,
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
-    B, S, D = x.shape
-    H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, Dh)
-    k = (x @ p["wk"].astype(x.dtype)).reshape(B, S, KV, Dh)
-    v = (x @ p["wv"].astype(x.dtype)).reshape(B, S, KV, Dh)
-    if cfg.use_rope:
-        q = cm.apply_rope(q, positions, cfg.rope_theta)
-        k = cm.apply_rope(k, positions, cfg.rope_theta)
+    with obs_trace.block("attention"):
+        B, S, D = x.shape
+        H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, Dh)
+        k = (x @ p["wk"].astype(x.dtype)).reshape(B, S, KV, Dh)
+        v = (x @ p["wv"].astype(x.dtype)).reshape(B, S, KV, Dh)
+        if cfg.use_rope:
+            q = cm.apply_rope(q, positions, cfg.rope_theta)
+            k = cm.apply_rope(k, positions, cfg.rope_theta)
 
-    if cache is None:
-        kv_pos = positions[0] if positions.ndim == 2 else positions
-        q_pos = (positions if positions.ndim == 2
-                 else jnp.broadcast_to(positions[None], (B, S)))
-        # ISSUE 9: training/prefill attention dispatches through the kernel
-        # registry. The ref backend reproduces the pre-kernel ops literally
-        # (including the chunk-gated _sdpa/_chunked_sdpa selection), so the
-        # default CPU path is unchanged; TPU / forced backends lower the
-        # blockwise flash Pallas kernel with its recompute-based VJP.
-        out = _flash_attention(
-            q, k, v, q_pos, kv_pos, local_flag,
-            softcap=cfg.attn_logit_softcap, window=cfg.sliding_window,
-            causal=causal, chunk=cfg.attn_chunk,
-        )
-        new_cache = None
-    else:
-        # decode: insert the S new k/v rows at cache_pos, attend over the
-        # cache. cache_pos is a scalar start (uniform batch — a contiguous
-        # dynamic_update_slice) or a (B,) vector of per-lane starts
-        # (continuous batching with staggered sequence lengths — a scatter).
-        T = cache["k"].shape[1]
-        if jnp.ndim(cache_pos) == 0:
-            ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, cache_pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, cache_pos, 0, 0))
-        else:
-            lane = jnp.arange(B)[:, None]
-            idx = cache_pos[:, None] + jnp.arange(S)
-            ck = cache["k"].at[lane, idx].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[lane, idx].set(v.astype(cache["v"].dtype))
-        if S == 1:
-            # one-token decode: the split-KV kernel consumes per-lane
-            # positions directly (continuous batching's ragged lanes); the
-            # ref backend is the exact make_mask + _sdpa ops from before.
-            out = _flash_decode(
-                q, ck.astype(q.dtype), cv.astype(q.dtype), positions,
-                local_flag, softcap=cfg.attn_logit_softcap,
-                window=cfg.sliding_window,
+        if cache is None:
+            kv_pos = positions[0] if positions.ndim == 2 else positions
+            q_pos = (positions if positions.ndim == 2
+                     else jnp.broadcast_to(positions[None], (B, S)))
+            # ISSUE 9: training/prefill attention dispatches through the kernel
+            # registry. The ref backend reproduces the pre-kernel ops literally
+            # (including the chunk-gated _sdpa/_chunked_sdpa selection), so the
+            # default CPU path is unchanged; TPU / forced backends lower the
+            # blockwise flash Pallas kernel with its recompute-based VJP.
+            out = _flash_attention(
+                q, k, v, q_pos, kv_pos, local_flag,
+                softcap=cfg.attn_logit_softcap, window=cfg.sliding_window,
+                causal=causal, chunk=cfg.attn_chunk,
             )
+            new_cache = None
         else:
-            kv_pos = jnp.arange(T)
-            mask = make_mask(positions, kv_pos, causal=True, local_flag=local_flag, window=cfg.sliding_window)
-            out = _sdpa(q, ck.astype(q.dtype), cv.astype(q.dtype), mask, softcap=cfg.attn_logit_softcap)
-        new_cache = {"k": ck, "v": cv}
+            # decode: insert the S new k/v rows at cache_pos, attend over the
+            # cache. cache_pos is a scalar start (uniform batch — a contiguous
+            # dynamic_update_slice) or a (B,) vector of per-lane starts
+            # (continuous batching with staggered sequence lengths — a scatter).
+            T = cache["k"].shape[1]
+            if jnp.ndim(cache_pos) == 0:
+                ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, cache_pos, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, cache_pos, 0, 0))
+            else:
+                lane = jnp.arange(B)[:, None]
+                idx = cache_pos[:, None] + jnp.arange(S)
+                ck = cache["k"].at[lane, idx].set(k.astype(cache["k"].dtype))
+                cv = cache["v"].at[lane, idx].set(v.astype(cache["v"].dtype))
+            if S == 1:
+                # one-token decode: the split-KV kernel consumes per-lane
+                # positions directly (continuous batching's ragged lanes); the
+                # ref backend is the exact make_mask + _sdpa ops from before.
+                out = _flash_decode(
+                    q, ck.astype(q.dtype), cv.astype(q.dtype), positions,
+                    local_flag, softcap=cfg.attn_logit_softcap,
+                    window=cfg.sliding_window,
+                )
+            else:
+                kv_pos = jnp.arange(T)
+                mask = make_mask(positions, kv_pos, causal=True, local_flag=local_flag, window=cfg.sliding_window)
+                out = _sdpa(q, ck.astype(q.dtype), cv.astype(q.dtype), mask, softcap=cfg.attn_logit_softcap)
+            new_cache = {"k": ck, "v": cv}
 
-    out = out.reshape(B, S, H * Dh) @ p["wo"].astype(x.dtype)
-    return out, new_cache
+        out = out.reshape(B, S, H * Dh) @ p["wo"].astype(x.dtype)
+        return out, new_cache
 
 
 def init_kv_cache(cfg, batch: int, length: int, dtype=jnp.bfloat16):
@@ -239,56 +241,57 @@ def _rmsnorm_vec(x, scale, eps=1e-6):
 
 
 def mla_attention(cfg, p, x, positions, *, cache=None, cache_pos=None):
-    B, S, D = x.shape
-    H = cfg.num_heads
-    r = cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with obs_trace.block("attention"):
+        B, S, D = x.shape
+        H = cfg.num_heads
+        r = cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
 
-    if "wq_a" in p:
-        q = _rmsnorm_vec(x @ p["wq_a"].astype(x.dtype), p["q_norm"]) @ p["wq_b"].astype(x.dtype)
-    else:
-        q = x @ p["wq"].astype(x.dtype)
-    q = q.reshape(B, S, H, dn + dr)
-    qn, qr = q[..., :dn], q[..., dn:]
-    qr = cm.apply_rope(qr, positions, cfg.rope_theta)
+        if "wq_a" in p:
+            q = _rmsnorm_vec(x @ p["wq_a"].astype(x.dtype), p["q_norm"]) @ p["wq_b"].astype(x.dtype)
+        else:
+            q = x @ p["wq"].astype(x.dtype)
+        q = q.reshape(B, S, H, dn + dr)
+        qn, qr = q[..., :dn], q[..., dn:]
+        qr = cm.apply_rope(qr, positions, cfg.rope_theta)
 
-    kv_a = x @ p["wkv_a"].astype(x.dtype)  # (B,S,r+dr)
-    ckv, krope = kv_a[..., :r], kv_a[..., r:]
-    krope = cm.apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]  # shared head
+        kv_a = x @ p["wkv_a"].astype(x.dtype)  # (B,S,r+dr)
+        ckv, krope = kv_a[..., :r], kv_a[..., r:]
+        krope = cm.apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]  # shared head
 
-    if cache is not None:
-        if jnp.ndim(cache_pos) == 0:
-            ckv = jax.lax.dynamic_update_slice(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, cache_pos, 0))
-            krope = jax.lax.dynamic_update_slice(
-                cache["krope"], krope.astype(cache["krope"].dtype), (0, cache_pos, 0)
-            )
-        else:  # per-lane starts (continuous batching): scatter rows
-            lane = jnp.arange(B)[:, None]
-            idx = cache_pos[:, None] + jnp.arange(S)
-            ckv = cache["ckv"].at[lane, idx].set(ckv.astype(cache["ckv"].dtype))
-            krope = cache["krope"].at[lane, idx].set(krope.astype(cache["krope"].dtype))
-        new_cache = {"ckv": ckv, "krope": krope}
-        T = ckv.shape[1]
-        kv_pos = jnp.arange(T)
-    else:
-        new_cache = None
-        T = S
-        kv_pos = positions[0] if positions.ndim == 2 else positions
+        if cache is not None:
+            if jnp.ndim(cache_pos) == 0:
+                ckv = jax.lax.dynamic_update_slice(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, cache_pos, 0))
+                krope = jax.lax.dynamic_update_slice(
+                    cache["krope"], krope.astype(cache["krope"].dtype), (0, cache_pos, 0)
+                )
+            else:  # per-lane starts (continuous batching): scatter rows
+                lane = jnp.arange(B)[:, None]
+                idx = cache_pos[:, None] + jnp.arange(S)
+                ckv = cache["ckv"].at[lane, idx].set(ckv.astype(cache["ckv"].dtype))
+                krope = cache["krope"].at[lane, idx].set(krope.astype(cache["krope"].dtype))
+            new_cache = {"ckv": ckv, "krope": krope}
+            T = ckv.shape[1]
+            kv_pos = jnp.arange(T)
+        else:
+            new_cache = None
+            T = S
+            kv_pos = positions[0] if positions.ndim == 2 else positions
 
-    kv = _rmsnorm_vec(ckv.astype(x.dtype), p["kv_norm"]) @ p["wkv_b"].astype(x.dtype)
-    kv = kv.reshape(B, T, H, dn + dv)
-    kn, v = kv[..., :dn], kv[..., dn:]
+        kv = _rmsnorm_vec(ckv.astype(x.dtype), p["kv_norm"]) @ p["wkv_b"].astype(x.dtype)
+        kv = kv.reshape(B, T, H, dn + dv)
+        kn, v = kv[..., :dn], kv[..., dn:]
 
-    scale = 1.0 / jnp.sqrt(dn + dr).astype(x.dtype)
-    scores = (
-        jnp.einsum("bshd,bthd->bhst", qn, kn)
-        + jnp.einsum("bshd,btd->bhst", qr, krope.astype(x.dtype))
-    ) * scale
-    mask = make_mask(positions, kv_pos, causal=True)  # (B,1,S,T)
-    scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(B, S, H * dv)
-    return out @ p["wo"].astype(x.dtype), new_cache
+        scale = 1.0 / jnp.sqrt(dn + dr).astype(x.dtype)
+        scores = (
+            jnp.einsum("bshd,bthd->bhst", qn, kn)
+            + jnp.einsum("bshd,btd->bhst", qr, krope.astype(x.dtype))
+        ) * scale
+        mask = make_mask(positions, kv_pos, causal=True)  # (B,1,S,T)
+        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(B, S, H * dv)
+        return out @ p["wo"].astype(x.dtype), new_cache
 
 
 def init_mla_cache(cfg, batch: int, length: int, dtype=jnp.bfloat16):
@@ -319,21 +322,23 @@ def cross_attention(cfg, p, x, *, memory=None, memory_kv=None):
     """memory: (B, M, D_mem) encoder/vision states, or precomputed memory_kv
     {"k","v"} (decode path — computed once at prefill)."""
 
-    B, S, D = x.shape
-    H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, Dh)
-    if memory_kv is None:
-        k = (memory @ p["wk"].astype(memory.dtype)).reshape(B, -1, KV, Dh).astype(x.dtype)
-        v = (memory @ p["wv"].astype(memory.dtype)).reshape(B, -1, KV, Dh).astype(x.dtype)
-    else:
-        k, v = memory_kv["k"].astype(x.dtype), memory_kv["v"].astype(x.dtype)
-    out = _sdpa(q, k, v, None)
-    return out.reshape(B, S, H * Dh) @ p["wo"].astype(x.dtype)
+    with obs_trace.block("cross_attention"):
+        B, S, D = x.shape
+        H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, Dh)
+        if memory_kv is None:
+            k = (memory @ p["wk"].astype(memory.dtype)).reshape(B, -1, KV, Dh).astype(x.dtype)
+            v = (memory @ p["wv"].astype(memory.dtype)).reshape(B, -1, KV, Dh).astype(x.dtype)
+        else:
+            k, v = memory_kv["k"].astype(x.dtype), memory_kv["v"].astype(x.dtype)
+        out = _sdpa(q, k, v, None)
+        return out.reshape(B, S, H * Dh) @ p["wo"].astype(x.dtype)
 
 
 def cross_kv(cfg, p, memory):
-    B = memory.shape[0]
-    KV, Dh = cfg.num_kv_heads, cfg.head_dim
-    k = (memory @ p["wk"].astype(memory.dtype)).reshape(B, -1, KV, Dh)
-    v = (memory @ p["wv"].astype(memory.dtype)).reshape(B, -1, KV, Dh)
-    return {"k": k, "v": v}
+    with obs_trace.block("cross_attention"):
+        B = memory.shape[0]
+        KV, Dh = cfg.num_kv_heads, cfg.head_dim
+        k = (memory @ p["wk"].astype(memory.dtype)).reshape(B, -1, KV, Dh)
+        v = (memory @ p["wv"].astype(memory.dtype)).reshape(B, -1, KV, Dh)
+        return {"k": k, "v": v}

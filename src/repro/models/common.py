@@ -12,6 +12,8 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as obs_trace
+
 PyTree = Any
 
 
@@ -51,15 +53,16 @@ def init_norm(cfg, d=None):
 
 
 def apply_norm(cfg, p, x, eps=1e-6):
-    xf = x.astype(jnp.float32)
-    if cfg.norm == "layernorm":
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        out = (xf - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-    else:  # rmsnorm
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
-    return out.astype(x.dtype)
+    with obs_trace.block("norm"):
+        xf = x.astype(jnp.float32)
+        if cfg.norm == "layernorm":
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            out = (xf - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
+        else:  # rmsnorm
+            ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+            out = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
+        return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -85,13 +88,14 @@ def init_mlp(cfg, key, d_in=None, d_ff=None, dtype=jnp.float32):
 
 
 def apply_mlp(cfg, p, x):
-    act = _act(cfg.act)
-    up = x @ p["up"].astype(x.dtype)
-    if cfg.mlp_type == "glu":
-        up = up * act(x @ p["gate"].astype(x.dtype))
-    else:
-        up = act(up)
-    return up @ p["down"].astype(x.dtype)
+    with obs_trace.block("mlp"):
+        act = _act(cfg.act)
+        up = x @ p["up"].astype(x.dtype)
+        if cfg.mlp_type == "glu":
+            up = up * act(x @ p["gate"].astype(x.dtype))
+        else:
+            up = act(up)
+        return up @ p["down"].astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ from repro.core.problems import PerExample
 from repro.models import transformer as tf
 from repro.kernels import dispatch as kdispatch
 from repro.kernels import ops as kops
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -41,16 +42,17 @@ def token_cross_entropy(
     path keeps logits dtype.
     """
 
-    if sharded:
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        lse = jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1)) + m[..., 0]
-        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-        tgt = jnp.sum(jnp.where(ids == targets[..., None], logits, 0.0), axis=-1)
-        return lse - tgt
-    if use_kernel or logits.shape[-1] >= kdispatch.CE_VOCAB_THRESHOLD:
-        return kops.cross_entropy(logits, targets)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with obs_trace.block("loss"):
+        if sharded:
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            lse = jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1)) + m[..., 0]
+            ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+            tgt = jnp.sum(jnp.where(ids == targets[..., None], logits, 0.0), axis=-1)
+            return lse - tgt
+        if use_kernel or logits.shape[-1] >= kdispatch.CE_VOCAB_THRESHOLD:
+            return kops.cross_entropy(logits, targets)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
 
 
 @dataclasses.dataclass(eq=False)  # identity hash/eq: Model instances key
@@ -85,12 +87,13 @@ class Model:                      # per-model jit caches (dataopt.prune)
         """Per-sequence loss for data-optimization meta learning."""
         logits, aux = self.forward(params, batch)
         del aux  # aux load-balance is added by train_loss wrappers, not reweighted
-        ce = token_cross_entropy(
-            logits[:, :-1], batch["tokens"][:, 1:], self.use_ce_kernel, self.cfg.sharded_ce
-        )
-        loss = jnp.mean(ce, axis=-1)  # (B,)
-        logp = jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), axis=-1)
-        entropy = -jnp.sum(jnp.exp(logp) * logp, axis=-1)
+        with obs_trace.block("loss"):
+            ce = token_cross_entropy(
+                logits[:, :-1], batch["tokens"][:, 1:], self.use_ce_kernel, self.cfg.sharded_ce
+            )
+            loss = jnp.mean(ce, axis=-1)  # (B,)
+            logp = jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), axis=-1)
+            entropy = -jnp.sum(jnp.exp(logp) * logp, axis=-1)
         return PerExample(loss=loss, uncertainty=entropy)
 
     def classifier_per_example(self, params, batch) -> PerExample:
@@ -98,14 +101,15 @@ class Model:                      # per-model jit caches (dataopt.prune)
         at ``kernels.CE_VOCAB_THRESHOLD``+ route the per-sample CE through
         the dispatched ``weighted_ce`` kernel (docs/kernels.md)."""
         logits, _ = self.forward(params, batch)
-        onehot = jax.nn.one_hot(batch["y"], logits.shape[-1], dtype=logits.dtype)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        if logits.shape[-1] >= kdispatch.CE_VOCAB_THRESHOLD:
-            loss = kops.cross_entropy(logits, batch["y"])
-        else:
-            loss = -jnp.sum(onehot * logp, axis=-1)
-        p = jnp.exp(logp)
-        entropy = -jnp.sum(p * logp, axis=-1)
+        with obs_trace.block("loss"):
+            onehot = jax.nn.one_hot(batch["y"], logits.shape[-1], dtype=logits.dtype)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            if logits.shape[-1] >= kdispatch.CE_VOCAB_THRESHOLD:
+                loss = kops.cross_entropy(logits, batch["y"])
+            else:
+                loss = -jnp.sum(onehot * logp, axis=-1)
+            p = jnp.exp(logp)
+            entropy = -jnp.sum(p * logp, axis=-1)
         return PerExample(loss=loss, logits=logits, label_onehot=onehot, uncertainty=entropy)
 
     def num_params(self, params) -> int:

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -96,34 +97,36 @@ def _group_dispatch(cfg, probs_g, tokens_g, experts, capacity):
 def apply_moe(cfg, p: PyTree, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D). Returns (out, aux_load_balance_loss)."""
 
-    B, S, D = x.shape
-    E, K = cfg.num_experts, cfg.top_k
-    T = B * S
-    group = min(MOE_GROUP, T)
-    n_groups = T // group
-    assert n_groups * group == T, f"token count {T} not divisible by group {group}"
-    capacity = max(int(cfg.capacity_factor * K * group / E), 4)
+    # the expert layer is an mlp block; "moe" inside it names this module
+    with obs_trace.block("mlp"), obs_trace.block("moe"):
+        B, S, D = x.shape
+        E, K = cfg.num_experts, cfg.top_k
+        T = B * S
+        group = min(MOE_GROUP, T)
+        n_groups = T // group
+        assert n_groups * group == T, f"token count {T} not divisible by group {group}"
+        capacity = max(int(cfg.capacity_factor * K * group / E), 4)
 
-    tokens = x.reshape(n_groups, group, D)
-    router_logits = jnp.einsum(
-        "ngd,de->nge", tokens.astype(jnp.float32), p["router"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (n, G, E)
+        tokens = x.reshape(n_groups, group, D)
+        router_logits = jnp.einsum(
+            "ngd,de->nge", tokens.astype(jnp.float32), p["router"].astype(jnp.float32)
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # (n, G, E)
 
-    out = jax.vmap(lambda pr, tk: _group_dispatch(cfg, pr, tk, p["experts"], capacity))(
-        probs, tokens
-    )
+        out = jax.vmap(lambda pr, tk: _group_dispatch(cfg, pr, tk, p["experts"], capacity))(
+            probs, tokens
+        )
 
-    flat_tokens = x.reshape(T, D)
-    if cfg.num_shared_experts:
-        out = out.reshape(T, D) + cm.apply_mlp(cfg, p["shared"], flat_tokens)
+        flat_tokens = x.reshape(T, D)
+        if cfg.num_shared_experts:
+            out = out.reshape(T, D) + cm.apply_mlp(cfg, p["shared"], flat_tokens)
 
-    # GShard aux loss: E * sum_e f_e * p_e over the whole batch
-    probs_flat = probs.reshape(T, E)
-    _, expert_idx = jax.lax.top_k(probs_flat, K)
-    assign = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)
-    me = jnp.mean(probs_flat, axis=0)
-    ce = jnp.mean(jnp.sum(assign, axis=1), axis=0)
-    aux = E * jnp.sum(me * ce) * cfg.router_aux_coef
+        # GShard aux loss: E * sum_e f_e * p_e over the whole batch
+        probs_flat = probs.reshape(T, E)
+        _, expert_idx = jax.lax.top_k(probs_flat, K)
+        assign = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)
+        me = jnp.mean(probs_flat, axis=0)
+        ce = jnp.mean(jnp.sum(assign, axis=1), axis=0)
+        aux = E * jnp.sum(me * ce) * cfg.router_aux_coef
 
-    return out.reshape(B, S, D), aux.astype(jnp.float32)
+        return out.reshape(B, S, D), aux.astype(jnp.float32)

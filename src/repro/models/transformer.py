@@ -32,6 +32,7 @@ from repro.models import attention as attn
 from repro.models import common as cm
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -102,18 +103,20 @@ def _embed(cfg, params, tokens, dtype, positions=None):
     None means tokens start at position 0 (the train/prefill case). Decode
     MUST pass real positions — indexing ``pos_embed[:S]`` there would add
     the position-0 embedding to every generated token."""
-    x = params["embed"][tokens].astype(dtype) * jnp.sqrt(cfg.d_model).astype(dtype)
-    if cfg.pos_embed == "learned":
-        if positions is None:
-            x = x + params["pos_embed"][: tokens.shape[1]].astype(dtype)
-        else:
-            x = x + params["pos_embed"][positions].astype(dtype)
-    return x
+    with obs_trace.block("embed"):
+        x = params["embed"][tokens].astype(dtype) * jnp.sqrt(cfg.d_model).astype(dtype)
+        if cfg.pos_embed == "learned":
+            if positions is None:
+                x = x + params["pos_embed"][: tokens.shape[1]].astype(dtype)
+            else:
+                x = x + params["pos_embed"][positions].astype(dtype)
+        return x
 
 
 def _unembed(cfg, params, x):
-    logits = x @ params["embed"].T.astype(x.dtype)
-    return cm.softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+    with obs_trace.block("unembed"):
+        logits = x @ params["embed"].T.astype(x.dtype)
+        return cm.softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
 
 
 # ===========================================================================
@@ -253,8 +256,9 @@ def forward(cfg, params: PyTree, batch: Dict[str, jnp.ndarray]) -> Tuple[jnp.nda
         x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, (params["layers"], flags))
         if fam == "encoder":
             x = cm.apply_norm(cfg, params["final_norm"], x)
-            cls = x[:, 0]
-            logits = cls @ params["cls_head"]["w"].astype(x.dtype) + params["cls_head"]["b"]
+            with obs_trace.block("unembed"):
+                cls = x[:, 0]
+                logits = cls @ params["cls_head"]["w"].astype(x.dtype) + params["cls_head"]["b"]
             return logits.astype(jnp.float32), aux
 
     elif fam == "moe":
@@ -326,31 +330,35 @@ def _whisper_forward(cfg, params, batch):
     dtype = cm.dtype_of(cfg.dtype)
     frames = batch["frames"].astype(dtype)  # (B, F, D) stubbed conv/mel output
     F = frames.shape[1]
-    enc = frames + cm.sinusoidal_pos(F, cfg.d_model, dtype)[None]
-    enc_pos = jnp.broadcast_to(jnp.arange(F), (frames.shape[0], F))
+    with obs_trace.block("encoder"):
+        with obs_trace.block("embed"):
+            enc = frames + cm.sinusoidal_pos(F, cfg.d_model, dtype)[None]
+        enc_pos = jnp.broadcast_to(jnp.arange(F), (frames.shape[0], F))
 
-    def ebody(h, lp):
-        hh = cm.apply_norm(cfg, lp["ln1"], h)
-        out, _ = attn.self_attention(cfg, lp["attn"], hh, enc_pos, causal=False)
-        h = h + out
-        h = h + cm.apply_mlp(cfg, lp["mlp"], cm.apply_norm(cfg, lp["ln2"], h))
-        return h, None
+        def ebody(h, lp):
+            hh = cm.apply_norm(cfg, lp["ln1"], h)
+            out, _ = attn.self_attention(cfg, lp["attn"], hh, enc_pos, causal=False)
+            h = h + out
+            h = h + cm.apply_mlp(cfg, lp["mlp"], cm.apply_norm(cfg, lp["ln2"], h))
+            return h, None
 
-    enc, _ = jax.lax.scan(_maybe_remat(cfg, ebody), enc, params["encoder"]["layers"])
-    memory = cm.apply_norm(cfg, params["encoder"]["norm"], enc)
+        enc, _ = jax.lax.scan(_maybe_remat(cfg, ebody), enc, params["encoder"]["layers"])
+        memory = cm.apply_norm(cfg, params["encoder"]["norm"], enc)
 
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = _embed(cfg, params, tokens, dtype)
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    with obs_trace.block("decoder"):
+        x = _embed(cfg, params, tokens, dtype)
+        positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    def dbody(h, lp):
-        h, _ = _dense_layer_with_cross(cfg, lp, h, positions, memory=memory)
-        return h, None
+        def dbody(h, lp):
+            h, _ = _dense_layer_with_cross(cfg, lp, h, positions, memory=memory)
+            return h, None
 
-    x, _ = jax.lax.scan(_maybe_remat(cfg, dbody), x, params["layers"])
-    x = cm.apply_norm(cfg, params["final_norm"], x)
-    return _unembed(cfg, params, x), jnp.zeros((), jnp.float32)
+        x, _ = jax.lax.scan(_maybe_remat(cfg, dbody), x, params["layers"])
+        x = cm.apply_norm(cfg, params["final_norm"], x)
+        logits = _unembed(cfg, params, x)
+    return logits, jnp.zeros((), jnp.float32)
 
 
 def _dense_layer_with_cross(cfg, p, x, positions, memory=None, memory_kv=None, cache=None, cache_pos=None):

@@ -23,11 +23,15 @@ instruction's cost to the *innermost* phase on its op_name path:
   peak live bytes. Buffer sizes are aval arithmetic over the printed
   shapes — the CPU-safe fallback of ``perf.memory``; loop internals are
   charged as their carried state.
+* **Modules** — FLOPs per source file of the op. Under remat, jax stamps
+  every op of a scanned layer with the scan's call site in
+  ``transformer.py``; such an op goes to the module that opened its
+  innermost model scope (``trace.BLOCKS``, see :data:`SCOPE_MODULES`).
 
-Joining with measured per-phase wall time (``Tracer.runtime_spans()``
-from ``MetaLearner.phase_profile()``) turns the static counts into
-achieved FLOP/s and utilization against the roofline peak
-(the roofline table's peak for the target chip by default).
+Joining with measured per-phase wall time (``Span`` objects or dicts
+named by phase, e.g. ``Tracer.runtime_spans()`` of an eager step) turns
+the static counts into achieved FLOP/s and utilization against the
+roofline peak (the roofline table's peak for the target chip by default).
 
 The result dict is the optional ``attribution`` section of a
 ``PerfRecord`` (schema v1, additive — ``perf.record.validate_attribution``)
@@ -49,13 +53,26 @@ from collections import defaultdict
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 from repro.roofline import hlo_parse
-from repro.obs.trace import PHASES
+from repro.obs.trace import PHASES, scope_names
 
 #: phase bucket for instructions carrying no recognized phase annotation
 OTHER = "other"
 
 #: default phase vocabulary: the engine phases plus serve's fused step
 DEFAULT_PHASES: Tuple[str, ...] = PHASES + ("serve_step",)
+
+#: the module that opens each model scope (``trace.BLOCKS``, and the MoE
+#: expert layer's ``moe`` inside its ``mlp``)
+SCOPE_MODULES = {
+    "embed": "transformer.py", "norm": "common.py",
+    "attention": "attention.py", "cross_attention": "attention.py",
+    "mlp": "common.py", "moe": "moe.py",
+    "unembed": "transformer.py", "loss": "model.py",
+}
+
+#: where the scanned layer stacks are called: jax lowers a remat'd scan
+#: body once and stamps every op inside with this file's call site
+SCAN_SITE = "transformer.py"
 
 _INSTR_RE = re.compile(r"^\s*(ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
 _OPCODE_RE = re.compile(r"([a-zA-Z][\w\-]*)\(")
@@ -240,6 +257,19 @@ def _module_of(source_file: str) -> Optional[str]:
     return source_file.rsplit("/", 1)[-1] if source_file else None
 
 
+def module_of(source_file: str, op_name: str) -> Optional[str]:
+    """The module an op's cost is charged to: its source file's, except
+    where that is the scan call site (:data:`SCAN_SITE`), which hides the
+    op's own file under remat; there the module that opened the innermost
+    model scope on ``op_name`` (:data:`SCOPE_MODULES`) owns it."""
+
+    mod = _module_of(source_file)
+    if mod == SCAN_SITE:
+        for name in scope_names(op_name):
+            mod = SCOPE_MODULES.get(name, mod)
+    return mod
+
+
 def _proto_fields(buf: bytes) -> Iterator[Tuple[int, Any]]:
     """(field number, value) pairs of one serialized protobuf message:
     varints as ints, length-delimited fields as bytes."""
@@ -406,8 +436,9 @@ def attribute(compiled_or_text: Any, *, phases: Optional[Sequence[str]] = None,
                 bucket["collective_bytes"] += ins.out_bytes * m
                 bucket["collective_count"] += m
             if flops:
-                mod = _module_of(ins.source_file
-                                 or frame_files.get(ins.stack_frame_id, ""))
+                mod = module_of(ins.source_file
+                                or frame_files.get(ins.stack_frame_id, ""),
+                                ins.op_name)
                 if mod:
                     per_module[mod] += flops
 

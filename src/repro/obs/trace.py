@@ -1,6 +1,6 @@
-"""Nested span timing over the engine's phases, with Chrome-trace export.
+"""Named scopes over the meta step, host spans, and Chrome-trace export.
 
-Two layers cooperate here, and keeping them straight is what makes the
+Three layers cooperate here, and keeping them straight is what makes the
 "byte-identical HLO when disabled" guarantee hold (tests/test_obs.py):
 
 1. **In-graph phase names** — :func:`phase` wraps each engine phase in
@@ -8,20 +8,27 @@ Two layers cooperate here, and keeping them straight is what makes the
    name metadata to the ops traced under it; it is applied whether or
    not observability is on, so the lowered HLO text is identical either
    way (and the `unroll+1` collective census is untouched).
-2. **Host span capture** — when a :class:`Tracer` is activated (a
+2. **In-graph block names** — :func:`block` is the same bare
+   ``jax.named_scope`` one level down, on each model block
+   (:data:`BLOCKS`) and on each stack of an encoder-decoder (``encoder``,
+   ``decoder``). It never records a host span: under ``jax.jit`` one
+   would only time tracing.
+   Both scope levels reach each compiled op's ``op_name``, so a device
+   trace of the jitted step can be split by phase and by block. Inside a
+   remat'd ``lax.scan`` body a block keeps its plain name; outside one, a
+   backward op carries it inside JAX's transform wrappers, e.g.
+   ``transpose(jvp(loss))`` (:func:`block_of` strips them).
+3. **Host span capture** — when a :class:`Tracer` is activated (a
    contextvar, see :func:`activate`), :func:`phase` ALSO records a host
    wall-time span and enters ``jax.profiler.TraceAnnotation`` so native
    JAX profiles carry the same labels. With no tracer active the extra
    cost is one contextvar read at Python execution time — which for
    jitted code means once per compilation, not per step.
 
-What a span's duration *means* depends on where Python ran:
-
-* under ``jax.jit`` tracing, the phase body executes once at trace time
-  — the span measures tracing cost and is tagged ``traced=True``;
-* eagerly (``MetaLearner.phase_profile()`` runs one step un-jitted),
-  the span measures real dispatch+compute wall time per phase — these
-  are the per-phase numbers ``repro.obs.report`` prints.
+Spans are stamped on the profiler's own clock (:func:`profiler_clock_ns`),
+so a span's start lines up with its TraceMe event, and with device ops, in
+a ``jax.profiler`` capture. A span recorded while jax was tracing measures
+tracing cost and is tagged ``traced=True``.
 
 Spans nest: ``depth`` and ``parent`` reconstruct the tree, and
 :func:`chrome_trace` emits ``traceEvents`` (``ph="X"``, µs timestamps)
@@ -51,11 +58,27 @@ PHASES = (
     "allreduce_flat",   # flat-bucket all-reduce (launch/distributed.py)
 )
 
+#: Model block names (:func:`block`), in forward order. A device op is
+#: charged to the innermost block on its ``op_name`` path; an op on no
+#: block's path (residual adds, optimizer updates, scan bookkeeping) is
+#: charged to none.
+BLOCKS = (
+    "embed",            # token (+ position) embedding (models/transformer._embed)
+    "norm",             # LayerNorm / RMSNorm (models/common.apply_norm)
+    "attention",        # self-attention: QKV/O projections + score/softmax/AV
+                        # (models/attention.self_attention, mla_attention)
+    "cross_attention",  # encoder-decoder attention (attention.cross_attention, cross_kv)
+    "mlp",              # dense MLP / GLU and the MoE expert layer
+                        # (models/common.apply_mlp, models/moe.apply_moe)
+    "unembed",          # vocabulary projection or classifier head
+    "loss",             # per-token / per-example cross-entropy (models/model.py)
+)
+
 
 @dataclasses.dataclass
 class Span:
     name: str
-    start_s: float          # perf_counter seconds (monotonic, not unix)
+    start_s: float          # profiler-clock seconds (profiler_clock_ns)
     dur_s: float
     depth: int
     parent: Optional[str]
@@ -70,6 +93,15 @@ class Span:
         return {"name": self.name, "start_s": self.start_s, "dur_s": self.dur_s,
                 "dur_us": self.dur_us, "depth": self.depth, "parent": self.parent,
                 "traced": self.traced, "step": self.step}
+
+
+def profiler_clock_ns() -> int:
+    """The clock ``jax.profiler`` stamps TraceMe events with: the realtime
+    clock. A capture's event times are offsets from its
+    ``profile_start_time`` (a stat of its ``Task Environment`` plane),
+    which is on this clock too."""
+
+    return time.time_ns()
 
 
 def _in_jax_trace() -> bool:
@@ -101,7 +133,7 @@ class Tracer:
         depth = len(self._stack)
         self._stack.append(name)
         traced = _in_jax_trace()
-        t0 = time.perf_counter()
+        t0 = profiler_clock_ns()
         try:
             if annotation is not None:
                 with annotation:
@@ -109,9 +141,9 @@ class Tracer:
             else:
                 yield
         finally:
-            dur = time.perf_counter() - t0
+            dur = (profiler_clock_ns() - t0) / 1e9
             self._stack.pop()
-            sp = Span(name=name, start_s=t0, dur_s=dur, depth=depth,
+            sp = Span(name=name, start_s=t0 / 1e9, dur_s=dur, depth=depth,
                       parent=parent, traced=traced, step=self.step)
             self.spans.append(sp)
             if self._obs is not None and self._obs.enabled:
@@ -168,6 +200,42 @@ def phase(name: str) -> Iterator[None]:
         else:
             with tracer.span(name):
                 yield
+
+
+def block(name: str):
+    """Name a model block (:data:`BLOCKS`), a stack of an encoder-decoder
+    (whisper's ``encoder`` and ``decoder``, so a block's path says which
+    stack it ran in) or a finer scope inside a block (the MoE expert
+    layer's ``moe``): a bare ``jax.named_scope``, metadata only and always
+    on. Unlike :func:`phase` it records no host span."""
+
+    import jax
+
+    return jax.named_scope(name)
+
+
+def scope_names(op_name: str) -> List[str]:
+    """The components of an ``op_name`` scope path, outermost first, with
+    JAX's transform wrappers stripped (``transpose(jvp(loss))`` reads as
+    ``loss``). Of a fused op's ``;``-joined names the first is read."""
+
+    out = []
+    for seg in op_name.split(";", 1)[0].split("/"):
+        while seg.endswith(")") and "(" in seg:
+            seg = seg[seg.index("(") + 1:-1]
+        out.append(seg)
+    return out
+
+
+def block_of(op_name: str) -> Optional[str]:
+    """The innermost :data:`BLOCKS` name on an ``op_name`` scope path
+    (:func:`scope_names`); None when the path names no block."""
+
+    found = None
+    for name in scope_names(op_name):
+        if name in BLOCKS:
+            found = name
+    return found
 
 
 # ---------------------------------------------------------------------------

@@ -62,8 +62,8 @@ def profile_step(name: str, fn, *args, samples_per_step: Optional[float] = None,
     ``attribution=True`` additionally partitions the compiled HLO's
     FLOPs/bytes/collectives by engine phase (``repro.obs.profile``) into
     the record's optional ``attribution`` section;
-    ``attribution_spans`` (measured ``Tracer`` spans, e.g. from
-    ``MetaLearner.phase_profile``) joins per-phase wall time and
+    ``attribution_spans`` (measured ``Tracer`` spans, e.g. of one eager
+    step under an activated tracer) joins per-phase wall time and
     roofline utilization into it."""
 
     m = measure(fn, *args, warmup=warmup, repeats=repeats)

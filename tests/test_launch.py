@@ -129,23 +129,27 @@ def _run_subprocess(script: str, timeout: int = 300) -> str:
 
 
 def test_compile_cache_dir_from_env_or_fixed_in_checkout(monkeypatch):
-    """JAX_COMPILATION_CACHE_DIR, when set, is used and nothing is
-    configured; otherwise the cache goes to <repo>/.jax_cache."""
+    """JAX_COMPILATION_CACHE_DIR, when set, is used and no directory is
+    configured; otherwise the cache goes to <repo>/.jax_cache. Either way
+    the ops' metadata (their scope names) is part of the cache key."""
     from pathlib import Path
 
     from repro.launch import compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
         assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
         assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         got = compile_cache.enable_compile_cache()
         repo = Path(__file__).resolve().parents[1]
         assert got == str(repo / ".jax_cache") == jax.config.jax_compilation_cache_dir
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_train_main_returns_rows_and_builds_mesh_from_devices():
