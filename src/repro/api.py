@@ -40,9 +40,11 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import sys
 from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import checkpoint, optim
 from repro.core.bilevel import BilevelSpec
@@ -62,7 +64,14 @@ _ENGINE_FIELDS = {f.name for f in dataclasses.fields(EngineConfig)}
 class MetaLearner:
     """High-level facade over the bilevel Engine and the distributed
     schedules. Holds the (pure) step function plus the current EngineState;
-    all mutation is confined to ``self.state``."""
+    all mutation is confined to ``self.state``.
+
+    The learner owns its state. ``init`` copies the arrays it is given, and
+    the jitted step donates ``self.state`` to XLA, which writes the new state
+    into the old one's buffers: a step holds one copy of theta and the
+    optimizer moments, not two. The old state's arrays are deleted; a caller
+    that still holds the state object (``before = learner.state``) keeps it,
+    because ``step`` then donates a copy (docs/api.md)."""
 
     def __init__(
         self,
@@ -125,19 +134,34 @@ class MetaLearner:
         else:
             step = make_meta_step(self.spec, self.base_opt, self.meta_opt, self.cfg)
         self.schedule = schedule
-        self.step_fn = jax.jit(step) if jit else step
+        self._step = step
+        self.step_fn = jax.jit(step, donate_argnums=(0,)) if jit else step
         # host-side count of dispatched steps: the profiler's step marker
         # reads it, where reading state.step would sync with the device
         self._dispatched = 0
 
     # -- lifecycle ---------------------------------------------------------
 
+    def _place(self, tree: PyTree, *, copy: bool = False) -> PyTree:
+        """``tree`` where the step returns the state: whole on every device
+        of the mesh, or where each leaf is without one. ``copy`` gives new
+        buffers even to a leaf that is there already."""
+        where = None if self.mesh is None else NamedSharding(self.mesh, PartitionSpec())
+        tree = jax.device_put(tree, where)
+        # copied in a second put: a copy made while moving a leaf onto the
+        # mesh may share the source's buffer on the device it came from
+        return jax.device_put(tree, where, may_alias=False) if copy else tree
+
     def init(self, theta: PyTree, lam: PyTree) -> EngineState:
         """Build the EngineState (both levels' params + optimizer moments;
         a loss-scaling precision policy additionally seeds its
-        LossScaleState from ``cfg.scale``)."""
-        self.state = init_state(theta, lam, self.base_opt, self.meta_opt,
-                                scale=self.cfg.scale)
+        LossScaleState from ``cfg.scale``) from copies of ``theta`` and
+        ``lam``, so that the donating step never deletes the caller's
+        arrays. Every leaf starts where the step returns it, so the first
+        step already donates it."""
+        theta, lam = self._place((theta, lam), copy=True)
+        self.state = self._place(init_state(theta, lam, self.base_opt, self.meta_opt,
+                                            scale=self.cfg.scale))
         self._dispatched = 0
         return self.state
 
@@ -148,18 +172,26 @@ class MetaLearner:
         The dispatch runs under ``jax.profiler.StepTraceAnnotation(
         "meta_step", step_num=n)``, so a profile marks each step. ``n`` is
         counted on the host, from 0 at ``init`` or from the restored step
-        at ``load``: nothing is read back from the device."""
+        at ``load``: nothing is read back from the device.
+
+        The jitted step donates the old state, whose arrays are deleted.
+        Where a caller still holds the old state object, the step donates a
+        copy of it instead, and the caller's object stays valid."""
 
         if self.state is None:
             raise RuntimeError("call init(theta, lam) or load(...) before step()")
+        state = self.state
+        # three references: self.state, `state` and getrefcount's argument
+        if self.step_fn is not self._step and sys.getrefcount(state) > 3:
+            state = self._place(state, copy=True)
         n = self._dispatched
         self._dispatched += 1
         with jax.profiler.StepTraceAnnotation("meta_step", step_num=n):
             if self.mesh is not None:
                 with self.mesh:
-                    self.state, metrics = self.step_fn(self.state, base_batches, meta_batch)
+                    self.state, metrics = self.step_fn(state, base_batches, meta_batch)
             else:
-                self.state, metrics = self.step_fn(self.state, base_batches, meta_batch)
+                self.state, metrics = self.step_fn(state, base_batches, meta_batch)
         return metrics
 
     def fit(
@@ -183,16 +215,16 @@ class MetaLearner:
         if self.state is None:
             raise RuntimeError("call init(theta, lam) or load(...) before fit()")
 
-        def step_adapter(state, base_batches, meta_batch):
-            assert state is self.state
-            metrics = self.step(base_batches, meta_batch)  # advances self.state
-            return self.state, metrics
+        # the loop carries no state: a reference to self.state held across
+        # the step would make step() donate a copy of it
+        def step_adapter(_, base_batches, meta_batch):
+            return None, self.step(base_batches, meta_batch)  # advances self.state
 
-        def on_step(i, state):
+        def on_step(i, _):
             if save_every and (i + 1) % save_every == 0:
                 self.save()
 
-        _, history = run_loop(step_adapter, self.state, batch_iter, steps,
+        _, history = run_loop(step_adapter, None, batch_iter, steps,
                               log_every, on_step=on_step,
                               obs=obs if obs is not None else self.obs)
         return history
@@ -216,14 +248,16 @@ class MetaLearner:
         Always profiles the JIT-COMPILED step (memory/collective accounting
         needs the compiled executable) — for a ``jit=False`` learner these
         are the numbers ``fit`` would see after ``jax.jit``, not its eager
-        per-call overhead. State advances are discarded: the probe operates
-        on a snapshot of ``self.state``."""
+        per-call overhead. State advances are discarded: the probe runs a
+        compile of the step that donates nothing, again and again on
+        ``self.state``, which it leaves as it was. So its memory breakdown
+        counts the state twice, where the training step aliases it."""
 
         from repro import perf
 
         if self.state is None:
             raise RuntimeError("call init(theta, lam) or load(...) before profile()")
-        fn = self.step_fn if hasattr(self.step_fn, "lower") else jax.jit(self.step_fn)
+        fn = jax.jit(self._step)
         args = (self.state, base_batches, meta_batch)
         rec_name = name or f"{self.method.name}_{self.schedule}"
         extra = {"method": self.method.name, "schedule": self.schedule,
@@ -325,7 +359,7 @@ class MetaLearner:
                     f"learner uses {mine!r}; construct a matching MetaLearner "
                     "(or restore via repro.checkpoint directly to override)"
                 )
-        self.state = state
+        self.state = self._place(state)
         self._dispatched = int(state.step)
         if self.obs.enabled:
             self.obs.emit("checkpoint", "restore", step=int(state.step),

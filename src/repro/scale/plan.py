@@ -124,8 +124,8 @@ def measure_peak(spec, base_opt, meta_opt, engine_cfg, state, base_batches,
     else:
         step = make_meta_step(spec, base_opt, meta_opt, engine_cfg)
 
-    def lower():
-        return jax.jit(step).lower(state, base_batches, meta_batch)
+    def lower():  # donating, as MetaLearner's step: the state is aliased
+        return jax.jit(step, donate_argnums=(0,)).lower(state, base_batches, meta_batch)
 
     if mesh is not None:
         with mesh:

@@ -326,8 +326,13 @@ def test_metalearner_profile_emits_valid_record():
     assert rec.collectives["total_count"] == 0  # single device: no collectives
     assert rec.extra == {"method": "sama", "schedule": "pjit", "unroll_steps": 2,
                          "microbatch": 1, "policy": "f32"}
-    # profiling is a probe, not training: state untouched
+    # profiling is a probe, not training: state untouched, its arrays alive
+    # (the probe's compile donates nothing) and still the learner's to step
     assert learner.state is state_before
+    assert not any(x.is_deleted() for x in jax.tree_util.tree_leaves(learner.state))
+    assert int(learner.state.step) == 0
+    learner.step(base, meta)
+    assert int(learner.state.step) == 1
     with pytest.raises(RuntimeError, match="before profile"):
         MetaLearner(spec, method="sama", unroll_steps=2).profile(base, meta)
 
